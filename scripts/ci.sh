@@ -8,6 +8,8 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
+# The tests run on the CPU; the chip is driven by chip_smoke.py instead.
+export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 
 # Perf smoke: the control plane must stay O(log n).  Building a 5k-node
 # FunctionTree plus 500 churn ops takes ~50 ms on the frontier/index paths
@@ -323,10 +325,9 @@ PY
 # undercut the retired per-depth sweep by >= 1.1x (measured 1.13x on this
 # workload — the stagger-serialized closures are mostly single-tree, so
 # same-depth merging was already free and the wide-front gain is bounded
-# by cross-depth rounds).  When jax is importable the pallas cap-chain
-# backend (engine="vector_jax") must stay bit-identical to the numpy
-# engines on a wave and a block wave; jax missing skips that half with a
-# notice — the numpy wide-front assert runs either way.
+# by cross-depth rounds).  The pallas cap-chain backend
+# (engine="vector_jax", run in the Pallas interpreter on the CPU) must stay
+# bit-identical to the numpy engine on a wave and a block wave.
 python - <<'PY'
 import time
 from repro.sim import ScaleConfig, WaveConfig, provision_wave, run_scale
@@ -348,28 +349,23 @@ assert ds["flows_vector"] > ds["flows_scalar"], (
     f"carrying the bulk of the work"
 )
 
-from repro.kernels.cap_chain import have_jax
+a = provision_wave("faasnet", 96, WaveConfig(engine="vector"))
+b = provision_wave("faasnet", 96, WaveConfig(engine="vector_jax"))
+assert a == b, (
+    "widefront smoke FAILED: vector_jax diverged from vector on the "
+    "96-VM wave"
+)
+from repro.core import shared_base_images
+from repro.sim import block_wave
 
-if have_jax():
-    a = provision_wave("faasnet", 96, WaveConfig(engine="vector"))
-    b = provision_wave("faasnet", 96, WaveConfig(engine="vector_jax"))
-    assert a == b, (
-        "widefront smoke FAILED: vector_jax diverged from vector on the "
-        "96-VM wave"
-    )
-    from repro.core import shared_base_images
-    from repro.sim import block_wave
-
-    img = shared_base_images(1, 1, image_bytes=96 << 20)[0]
-    bv = block_wave("faasnet", 12, WaveConfig(engine="vector"), images=img)
-    bj = block_wave("faasnet", 12, WaveConfig(engine="vector_jax"), images=img)
-    assert bv == bj, (
-        "widefront smoke FAILED: vector_jax diverged from vector on the "
-        "block wave"
-    )
-    jax_note = "vector_jax bit-identical on wave + block wave"
-else:
-    jax_note = "jax not importable — vector_jax smoke SKIPPED (numpy-only host)"
+img = shared_base_images(1, 1, image_bytes=96 << 20)[0]
+bv = block_wave("faasnet", 12, WaveConfig(engine="vector"), images=img)
+bj = block_wave("faasnet", 12, WaveConfig(engine="vector_jax"), images=img)
+assert bv == bj, (
+    "widefront smoke FAILED: vector_jax diverged from vector on the "
+    "block wave"
+)
+jax_note = "vector_jax bit-identical on wave + block wave"
 elapsed = time.perf_counter() - t0
 budget = 20.0
 assert elapsed < budget, (

@@ -32,6 +32,7 @@ import numpy as np
 
 from repro.core.blockstore import (
     DEFAULT_BLOCK_SIZE,
+    BlockManifest,
     BlockReader,
     write_blockstore,
 )
@@ -166,8 +167,13 @@ class CheckpointManager:
             return jnp.asarray(arr.view(jnp.bfloat16))
         return jnp.asarray(np.frombuffer(raw, np.dtype(meta.dtype)).reshape(meta.shape))
 
+    def block_manifest(self, step: int) -> BlockManifest:
+        """Codec, raw size and compressed block offsets of checkpoint ``step``."""
+        doc, _ = self._load_manifest(step)
+        return BlockManifest.from_dict(doc["block_manifest"])
+
     def restore(self, step: int, like: PyTree) -> PyTree:
-        """Full restore into the structure of ``like``."""
+        """Full restore into the structure of ``like`` (arrays or shapes)."""
         doc, metas = self._load_manifest(step)
         with BlockReader(self._paths(step)[0]) as reader:
             by_path = {m.path: m for m in metas}

@@ -25,8 +25,10 @@ all replicas.  Schedules (selectable, compared in §Perf):
     bandwidth (§3.5).
 
 All schedules run inside shard_map over the data axes with ``lax.ppermute``
-and are exact: non-root replicas start from garbage and end bit-identical
-to the root (tested on a CPU mesh).
+and are exact. Each replica passes in its own buffer (``root_rows`` gives
+every non-root replica zeros), so the root's bytes reach the others only
+through the schedule, and every device ends bit-identical to the root
+(tested on a CPU mesh).
 """
 from __future__ import annotations
 
@@ -38,9 +40,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 
 PyTree = Any
 SCHEDULES = ("naive", "allgather", "binomial", "pipelined")
@@ -192,6 +193,64 @@ class BroadcastReport:
         return self.serialized_bytes / link_bw
 
 
+def _data_axes(mesh: Mesh) -> tuple[tuple[str, ...], int]:
+    axes = tuple(a for a in mesh.axis_names if a in ("pod", "data"))
+    return axes, int(np.prod([mesh.shape[a] for a in axes]))
+
+
+def _rounds(schedule: str, dp: int, n_blocks: int) -> tuple[Any, int]:
+    """Static per-round plan for ``_bcast_body`` and the number of rounds."""
+    if schedule == "pipelined":
+        info = faasnet_rounds(dp, n_blocks)
+        return info, len(info)
+    if schedule == "binomial":
+        info = binomial_rounds(dp)
+        return info, len(info)
+    if schedule == "naive":
+        return None, dp - 1
+    if schedule == "allgather":
+        return None, 1
+    raise ValueError(f"schedule {schedule!r} not in {SCHEDULES}")
+
+
+def root_rows(flat: jax.Array, mesh: Mesh) -> jax.Array:
+    """Per-replica input for :func:`broadcast_fn`: a ``(dp * n,)`` array whose
+    data-replica 0 holds ``flat`` and every other replica holds zeros, each
+    on its own devices, so no byte of the root's reaches them beforehand.
+    Where ``flat`` already sits on a root device it is used in place, and
+    the broadcast, which donates its input, consumes it."""
+    axes, dp = _data_axes(mesh)
+    n = flat.shape[0]
+    sharding = NamedSharding(mesh, P(axes))
+    rows = []
+    for dev, idx in sharding.addressable_devices_indices_map((dp * n,)).items():
+        if (idx[0].start or 0) == 0:
+            rows.append(jax.device_put(flat, dev))
+        else:
+            rows.append(jnp.zeros((n,), flat.dtype, device=dev))
+    return jax.make_array_from_single_device_arrays((dp * n,), sharding, rows)
+
+
+def broadcast_fn(mesh: Mesh, *, schedule: str = "pipelined", n_blocks: int = 32):
+    """Jitted broadcast from data-replica 0 of a ``(dp * n,)`` per-replica
+    payload (see :func:`root_rows`); the input is donated.
+
+    Returns an ``(n,)`` array whose shard on every device is what that
+    device received, which is the root's row once the schedule is exact.
+    The rows stay 1-D: a ``(1, n)`` row would be padded by the TPU's tiled
+    layout.
+    """
+    axes, dp = _data_axes(mesh)
+    rounds_info, _ = _rounds(schedule, dp, n_blocks)
+    body = partial(
+        _bcast_body, axes=axes, dp=dp, schedule=schedule,
+        n_blocks=n_blocks, rounds_info=rounds_info,
+    )
+    fn = jax.shard_map(body, mesh=mesh, in_specs=P(axes), out_specs=P(),
+                   check_vma=False)
+    return jax.jit(fn, donate_argnums=0)
+
+
 def tree_broadcast(
     params: PyTree,
     mesh: Mesh,
@@ -203,12 +262,11 @@ def tree_broadcast(
 ) -> tuple[PyTree, BroadcastReport]:
     """Replicate ``params`` from DP-replica 0 to all DP replicas.
 
-    Params are assumed sharded over the model axis only (each data replica
-    holds a full model-shard copy — possibly stale/garbage on non-root
-    replicas).  Returns (params, report).
+    Params are assumed sharded over the model axis only; the other data
+    replicas start from zeros. Returns (params, report).
     """
-    axes = tuple(a for a in mesh.axis_names if a in ("pod", "data"))
-    dp = int(np.prod([mesh.shape[a] for a in axes]))
+    axes, dp = _data_axes(mesh)
+    _, n_rounds = _rounds(schedule, dp, n_blocks)
     flat, spec = flatten_pytree(params, dtype=dtype, pad_to=n_blocks)
     if compress:
         from repro.optim.compress import dequantize_int8, quantize_int8
@@ -218,38 +276,13 @@ def tree_broadcast(
         scale_flat = scale.reshape(-1)
     else:
         payload = flat
+    per_round = payload.nbytes // n_blocks if schedule == "pipelined" else payload.nbytes
+    ser_bytes = (dp if schedule == "allgather" else n_rounds) * per_round
 
-    if schedule == "pipelined":
-        rounds_info = faasnet_rounds(dp, n_blocks)
-        n_rounds = len(rounds_info)
-        ser_bytes = n_rounds * (payload.nbytes // n_blocks)
-    elif schedule == "binomial":
-        rounds_info = binomial_rounds(dp)
-        n_rounds = len(rounds_info)
-        ser_bytes = n_rounds * payload.nbytes
-    elif schedule == "naive":
-        rounds_info = None
-        n_rounds = dp - 1
-        ser_bytes = (dp - 1) * payload.nbytes
-    elif schedule == "allgather":
-        rounds_info = None
-        n_rounds = 1
-        ser_bytes = dp * payload.nbytes
-    else:
-        raise ValueError(f"schedule {schedule!r} not in {SCHEDULES}")
-
-    body = partial(
-        _bcast_body, axes=axes, dp=dp, schedule=schedule,
-        n_blocks=n_blocks, rounds_info=rounds_info,
-    )
-    # payload replicated over every mesh axis; ppermute moves it over the
-    # data axes (each data replica holds its own full copy conceptually)
-    fn = shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
-                   check_vma=False)
-    new_payload = fn(payload)
+    fn = broadcast_fn(mesh, schedule=schedule, n_blocks=n_blocks)
+    new_payload = fn(root_rows(payload, mesh))
     if compress:
-        sc = shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
-                       check_vma=False)(scale_flat)
+        sc = fn(root_rows(scale_flat, mesh))
         deq = dequantize_int8(new_payload.reshape(n_blocks, -1), sc)
         flat_out = deq.reshape(-1)
     else:

@@ -12,150 +12,70 @@ minimum chain over per-flow gathered operands::
 
 The numpy path in :class:`repro.sim.vector_engine.VectorFlowSim` pays ~10
 separate elementwise dispatches per front for this; here the whole chain is
-one fused pallas kernel over the front (``cap_chain_rates``), plus a
-segment-reduction kernel for the per-NIC active-flow counts that feed the
-equal-split denominators (``nic_flow_counts``).
+one fused pallas kernel over the front (``cap_chain_rates``).
 
 Bit-identity contract: the kernel runs in **float64** (under
-``jax.experimental.enable_x64``, scoped so the rest of the process keeps
-default jax dtype promotion) and performs the identical IEEE-754 divisions
-and minima on the identical operands as the numpy/scalar paths, so the
-resulting rates — and therefore the engine's event log — are bit-identical,
-not merely close.  ``tests/test_vector_engine.py`` pins this with a
-four-way differential.  Like the other kernels in this package the pallas
-call runs in interpret mode on CPU hosts; when jax is absent entirely the
-callers fall back to the numpy reference (``cap_chain_rates_np``), which is
-also the oracle the kernel is tested against.
+``jax.enable_x64``, scoped so the rest of the process keeps default jax
+dtype promotion) and performs the identical IEEE-754 divisions and minima
+on the identical operands as the numpy path, so the resulting rates — and
+therefore the engine's event log — are bit-identical, not merely close.
+``tests/test_vector_engine.py`` pins this with a four-way differential.
+
+The TPU v5e compiler refuses this float64 kernel, so it runs only in the
+Pallas interpreter, and the caller says so with ``interpret=True``.
 """
 from __future__ import annotations
 
 import functools
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
 
-try:  # pragma: no cover - exercised via have_jax() at runtime
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
-    from jax.experimental import pallas as pl
-
-    _HAVE_JAX = True
-except Exception:  # pragma: no cover - jax genuinely absent on this host
-    _HAVE_JAX = False
-
-__all__ = [
-    "have_jax",
-    "cap_chain_rates",
-    "cap_chain_rates_np",
-    "nic_flow_counts",
-    "nic_flow_counts_np",
-]
+__all__ = ["cap_chain_rates"]
 
 # Pallas block width for the 1-D front; fronts are padded up to a multiple
 # with neutral operands (n_out=n_in=1, caps=+inf) and sliced back.
 _BLK = 256
 
 
-def have_jax() -> bool:
-    """True when the jax/pallas path is importable on this host."""
-    return _HAVE_JAX
-
-
 # ----------------------------------------------------------------------
-# numpy reference (and jax-absent fallback)
+# pallas kernel
 # ----------------------------------------------------------------------
-def cap_chain_rates_np(
-    n_out,
-    n_in,
-    out_cap,
-    qps,
-    par_rate,
-    blk,
-    *,
-    per_stream_cap: float,
-    in_cap: float,
-    decompress_rate: float,
-    block_size: float,
-) -> np.ndarray:
-    """Reference min-cap chain: same operand order as the fused kernel."""
-    n_out = np.asarray(n_out, dtype=np.float64)
-    r = np.minimum(per_stream_cap, np.asarray(out_cap, dtype=np.float64) / n_out)
-    r = np.minimum(r, in_cap / np.asarray(n_in, dtype=np.float64))
-    r = np.minimum(r, decompress_rate)
-    b = np.asarray(blk, dtype=bool)
-    if b.any():
-        q = block_size * np.asarray(qps, dtype=np.float64) / n_out
-        r = np.where(b, np.minimum(r, q), r)
-    return np.minimum(r, np.asarray(par_rate, dtype=np.float64))
+def _cap_chain_kernel(
+    n_out_ref, n_in_ref, out_cap_ref, qps_ref, par_ref, blk_ref, caps_ref,
+    r_ref,
+):
+    n_out = n_out_ref[...]
+    per_stream = caps_ref[0]
+    in_cap = caps_ref[1]
+    dec = caps_ref[2]
+    bsz = caps_ref[3]
+    r = jnp.minimum(per_stream, out_cap_ref[...] / n_out)
+    r = jnp.minimum(r, in_cap / n_in_ref[...])
+    r = jnp.minimum(r, dec)
+    # Block-mode flows add the shard QPS throttle; computed for every lane
+    # (qps=+inf on VM sources keeps it neutral) and masked in.
+    r = jnp.where(blk_ref[...], jnp.minimum(r, bsz * qps_ref[...] / n_out), r)
+    r_ref[...] = jnp.minimum(r, par_ref[...])
 
 
-def nic_flow_counts_np(nodes, n_nodes: int) -> np.ndarray:
-    """Reference segment reduction: active-flow count per NIC index."""
-    return np.bincount(np.asarray(nodes, dtype=np.int64), minlength=n_nodes)
-
-
-# ----------------------------------------------------------------------
-# pallas kernels
-# ----------------------------------------------------------------------
-if _HAVE_JAX:
-
-    def _cap_chain_kernel(
-        n_out_ref, n_in_ref, out_cap_ref, qps_ref, par_ref, blk_ref, caps_ref,
-        r_ref,
-    ):
-        n_out = n_out_ref[...]
-        per_stream = caps_ref[0]
-        in_cap = caps_ref[1]
-        dec = caps_ref[2]
-        bsz = caps_ref[3]
-        r = jnp.minimum(per_stream, out_cap_ref[...] / n_out)
-        r = jnp.minimum(r, in_cap / n_in_ref[...])
-        r = jnp.minimum(r, dec)
-        # Block-mode flows add the shard QPS throttle; computed for every
-        # lane (qps=+inf on VM sources keeps it neutral) and masked in.
-        r = jnp.where(
-            blk_ref[...], jnp.minimum(r, bsz * qps_ref[...] / n_out), r
-        )
-        r_ref[...] = jnp.minimum(r, par_ref[...])
-
-    @functools.partial(jax.jit, static_argnames=("interpret",))
-    def _cap_chain_call(n_out, n_in, out_cap, qps, par, blk, caps, *, interpret):
-        n = n_out.shape[0]
-        spec = pl.BlockSpec((_BLK,), lambda i: (i,))
-        return pl.pallas_call(
-            _cap_chain_kernel,
-            grid=(n // _BLK,),
-            in_specs=[
-                spec, spec, spec, spec, spec, spec,
-                pl.BlockSpec((4,), lambda i: (0,)),
-            ],
-            out_specs=spec,
-            out_shape=jax.ShapeDtypeStruct((n,), n_out.dtype),
-            interpret=interpret,
-        )(n_out, n_in, out_cap, qps, par, blk, caps)
-
-    def _count_kernel(nodes_ref, cnt_ref, *, n_nodes):
-        @pl.when(pl.program_id(0) == 0)
-        def _init():
-            cnt_ref[...] = jnp.zeros_like(cnt_ref)
-
-        # Scatter-add bincount for this chunk; padded lanes carry index
-        # n_nodes and land in the sacrificial overflow slot sliced off below.
-        cnt = cnt_ref[...]
-        cnt_ref[...] = cnt.at[nodes_ref[...]].add(1)
-
-    @functools.partial(jax.jit, static_argnames=("n_nodes", "interpret"))
-    def _count_call(nodes, *, n_nodes, interpret):
-        n = nodes.shape[0]
-        kernel = functools.partial(_count_kernel, n_nodes=n_nodes)
-        return pl.pallas_call(
-            kernel,
-            grid=(n // _BLK,),
-            in_specs=[pl.BlockSpec((_BLK,), lambda i: (i,))],
-            out_specs=pl.BlockSpec((n_nodes + 1,), lambda i: (0,)),
-            out_shape=jax.ShapeDtypeStruct((n_nodes + 1,), nodes.dtype),
-            interpret=interpret,
-        )(nodes)
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _cap_chain_call(n_out, n_in, out_cap, qps, par, blk, caps, *, interpret):
+    n = n_out.shape[0]
+    spec = pl.BlockSpec((_BLK,), lambda i: (i,))
+    return pl.pallas_call(
+        _cap_chain_kernel,
+        grid=(n // _BLK,),
+        in_specs=[
+            spec, spec, spec, spec, spec, spec,
+            pl.BlockSpec((4,), lambda i: (0,)),
+        ],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((n,), n_out.dtype),
+        interpret=interpret,
+    )(n_out, n_in, out_cap, qps, par, blk, caps)
 
 
 def _pad(a: np.ndarray, pad: int, value) -> np.ndarray:
@@ -165,7 +85,7 @@ def _pad(a: np.ndarray, pad: int, value) -> np.ndarray:
 
 
 # ----------------------------------------------------------------------
-# public entry points
+# public entry point
 # ----------------------------------------------------------------------
 def cap_chain_rates(
     n_out,
@@ -179,24 +99,15 @@ def cap_chain_rates(
     in_cap: float,
     decompress_rate: float,
     block_size: float,
-    interpret: bool = True,
+    interpret: bool,
 ) -> np.ndarray:
     """Fused per-flow min-cap chain over one recompute front.
 
     All array inputs are per-flow gathers of length ``len(front)``; counts
     may be integer dtype (converted exactly to float64 — fleet counts are
-    far below 2**53).  Returns float64 rates bit-identical to
-    :func:`cap_chain_rates_np`.  Falls back to the numpy reference when jax
-    is unavailable.
+    far below 2**53).  Returns float64 rates bit-identical to the numpy
+    path of :class:`repro.sim.vector_engine.VectorFlowSim`.
     """
-    if not _HAVE_JAX:
-        return cap_chain_rates_np(
-            n_out, n_in, out_cap, qps, par_rate, blk,
-            per_stream_cap=per_stream_cap,
-            in_cap=in_cap,
-            decompress_rate=decompress_rate,
-            block_size=block_size,
-        )
     n = len(n_out)
     pad = (-n) % _BLK
     no = _pad(np.asarray(n_out, dtype=np.float64), pad, 1.0)
@@ -209,9 +120,9 @@ def cap_chain_rates(
         [per_stream_cap, in_cap, decompress_rate, block_size], dtype=np.float64
     )
     # x64 scoped to the call: the kernel must trace and run in float64 for
-    # bit-identity with the numpy oracle, without flipping global jax
+    # bit-identity with the numpy path, without flipping global jax
     # promotion for other float32 kernels in the same process.
-    with enable_x64():
+    with jax.enable_x64(True):
         out = _cap_chain_call(
             jnp.asarray(no), jnp.asarray(ni), jnp.asarray(oc), jnp.asarray(qp),
             jnp.asarray(pr), jnp.asarray(bk), jnp.asarray(caps),
@@ -219,20 +130,3 @@ def cap_chain_rates(
         )
         res = np.asarray(out)
     return res[:n] if pad else res
-
-
-def nic_flow_counts(nodes, n_nodes: int, *, interpret: bool = True) -> np.ndarray:
-    """Segment-reduced active-flow counts per NIC (scatter-add bincount).
-
-    Validates the engine's incrementally-maintained ``_nout_cnt``/
-    ``_nin_cnt`` arrays; numpy ``bincount`` fallback when jax is absent.
-    """
-    if not _HAVE_JAX:
-        return nic_flow_counts_np(nodes, n_nodes)
-    nodes = np.asarray(nodes, dtype=np.int64)
-    pad = (-len(nodes)) % _BLK
-    padded = _pad(nodes, pad, n_nodes)  # overflow slot catches pad lanes
-    with enable_x64():
-        out = _count_call(jnp.asarray(padded), n_nodes=n_nodes, interpret=interpret)
-        res = np.asarray(out)
-    return res[:n_nodes]

@@ -34,18 +34,19 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, acc_ref, m_ref, l_ref,
     k = k_ref[0].astype(jnp.float32)  # (bs, hd)
     v = v_ref[0].astype(jnp.float32)
     s = (q @ k.T) * scale  # (1, bs)
-    s = jnp.where(valid_ref[0][None, :] > 0, s, NEG_INF)
-    m_prev = m_ref[0, 0]
-    m_new = jnp.maximum(m_prev, s.max())
+    s = jnp.where(valid_ref[0] > 0, s, NEG_INF)
+    # softmax state stays (1, 1) vectors: Mosaic cannot store scalars to VMEM
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
-    l_ref[0, 0] = l_ref[0, 0] * alpha + p.sum()
+    l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
     acc_ref[...] = acc_ref[...] * alpha + p @ v
-    m_ref[0, 0] = m_new
+    m_ref[...] = m_new
 
     @pl.when(si == ns - 1)
     def _finalize():
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[0, 0], 1e-30)).astype(
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
             o_ref.dtype
         )
 
@@ -55,11 +56,11 @@ def decode_attention_bhsd(
     q: jax.Array,  # (BH, 1, hd)
     k: jax.Array,  # (BH, S, hd)
     v: jax.Array,
-    valid: jax.Array,  # (BH, S) int32 — 1 where the slot holds a real key
+    valid: jax.Array,  # (BH, 1, S) int32 — 1 where the slot holds a real key
     *,
     scale: float,
     bs: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     bh, _, hd = q.shape
     s = k.shape[1]
@@ -74,7 +75,7 @@ def decode_attention_bhsd(
             pl.BlockSpec((1, 1, hd), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, bs, hd), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, bs, hd), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, bs), lambda b, j: (b, j)),
+            pl.BlockSpec((1, 1, bs), lambda b, j: (b, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, 1, hd), lambda b, j: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, 1, hd), q.dtype),

@@ -82,7 +82,7 @@ def flash_attention_bhtd(
     window: int | None = None,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     bh, t, hd = q.shape
     s = k.shape[1]

@@ -1,8 +1,9 @@
 """Jit'd public wrappers over the Pallas kernels (model-facing API).
 
-``interpret`` defaults to True on CPU hosts (this container) and should be
-False on real TPU backends; the models only route here when
-``cfg.attn_impl == "pallas"``.
+The kernels compile for a TPU. On any other backend a call raises unless
+the caller asks for the Pallas interpreter with ``interpret=True``, as the
+CPU tests do; nothing falls back to the interpreter on its own. The models
+only route here when ``cfg.attn_impl == "pallas"``.
 """
 from __future__ import annotations
 
@@ -18,11 +19,19 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _check_backend(interpret: bool) -> bool:
+    if not interpret and not _on_tpu():
+        raise RuntimeError(
+            f"the Pallas kernels compile only for a TPU, and the backend is "
+            f"{jax.default_backend()!r}; pass interpret=True to run them in "
+            f"the Pallas interpreter"
+        )
+    return interpret
+
+
 def flash_attention(q, k, v, *, q_pos=None, k_pos=None, window=None, scale,
-                    interpret=None):
+                    interpret=False):
     """(B,H,T,hd) attention; positions must be contiguous from 0."""
-    if interpret is None:
-        interpret = not _on_tpu()
     b, h, t, hd = q.shape
     s = k.shape[2]
     out = flash_attention_bhtd(
@@ -31,35 +40,31 @@ def flash_attention(q, k, v, *, q_pos=None, k_pos=None, window=None, scale,
         v.reshape(b * h, s, hd),
         scale=scale,
         window=window,
-        interpret=interpret,
+        interpret=_check_backend(interpret),
     )
     return out.reshape(b, h, t, hd)
 
 
-def decode_attention(q, k, v, valid, *, scale, interpret=None):
+def decode_attention(q, k, v, valid, *, scale, interpret=False):
     """q (B,H,1,hd), k/v (B,H,S,hd), valid (S,) or (B,S)."""
-    if interpret is None:
-        interpret = not _on_tpu()
     b, h, _, hd = q.shape
     s = k.shape[2]
     if valid.ndim == 1:
         valid = jnp.broadcast_to(valid[None], (b, s))
-    validbh = jnp.broadcast_to(valid[:, None, :], (b, h, s)).reshape(b * h, s)
+    validbh = jnp.broadcast_to(valid[:, None, :], (b, h, s)).reshape(b * h, 1, s)
     out = decode_attention_bhsd(
         q.reshape(b * h, 1, hd),
         k.reshape(b * h, s, hd),
         v.reshape(b * h, s, hd),
         validbh.astype(jnp.int32),
         scale=scale,
-        interpret=interpret,
+        interpret=_check_backend(interpret),
     )
     return out.reshape(b, h, 1, hd)
 
 
-def ssd_scan(x, dt, a, b, c, *, chunk=128, interpret=None):
+def ssd_scan(x, dt, a, b, c, *, chunk=128, interpret=False):
     """x (B,T,H,P), dt (B,T,H), a (H,), b/c (B,T,G,N) with G broadcast to H."""
-    if interpret is None:
-        interpret = not _on_tpu()
     bsz, t, h, p = x.shape
     g, n = b.shape[2], b.shape[3]
     rep = h // g
@@ -68,10 +73,10 @@ def ssd_scan(x, dt, a, b, c, *, chunk=128, interpret=None):
     out = ssd_scan_bhtpn(
         x.transpose(0, 2, 1, 3).reshape(bsz * h, t, p),
         dt.transpose(0, 2, 1).reshape(bsz * h, t, 1),
-        jnp.broadcast_to(a[None], (bsz, h)).reshape(bsz * h, 1),
+        jnp.broadcast_to(a[None], (bsz, h)).reshape(bsz * h, 1, 1),
         bh.transpose(0, 2, 1, 3).reshape(bsz * h, t, n),
         ch.transpose(0, 2, 1, 3).reshape(bsz * h, t, n),
         q=chunk,
-        interpret=interpret,
+        interpret=_check_backend(interpret),
     )
     return out.reshape(bsz, h, t, p).transpose(0, 2, 1, 3)

@@ -7,7 +7,7 @@ dense SSD algebra (segment-sum decay matrix, C·Bᵀ scores, state update) as
 recurrence, which is the SSD insight mapped onto the TPU: the only true
 sequential dependency is the tiny (P×N) state hop between chunks.
 
-Shapes per program: x (Q,P), dt (Q,1), B/C (Q,N), A scalar (per head).
+Shapes per program: x (Q,P), dt (Q,1), B/C (Q,N), A (1,1,1) (per head).
 """
 from __future__ import annotations
 
@@ -28,30 +28,35 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *, q):
 
     x = x_ref[0].astype(jnp.float32)  # (Q, P)
     dt = dt_ref[0].astype(jnp.float32)  # (Q, 1)
-    A = a_ref[0, 0]  # scalar log-decay rate (negative)
+    A = a_ref[0, 0, 0]  # scalar log-decay rate (negative)
     B = b_ref[0].astype(jnp.float32)  # (Q, N)
     C = c_ref[0].astype(jnp.float32)  # (Q, N)
 
-    a = dt[:, 0] * A  # (Q,) per-step log decay
-    a_cum = jnp.cumsum(a)  # (Q,)
-
-    # intra-chunk: L[i,j] = exp(sum_{j<s<=i} a_s) for j <= i
-    diff = a_cum[:, None] - a_cum[None, :]  # (Q, Q)
+    a = dt * A  # (Q, 1) per-step log decay
+    # Prefix sums as masked reductions: Mosaic lowers no cumsum, and these
+    # give the running sum along both the sublane and the lane axis without
+    # a transpose.
     ii = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    L = jnp.where(ii >= jj, jnp.exp(diff), 0.0)
+    a_row = jnp.sum(jnp.where(ii == jj, a, 0.0), axis=0, keepdims=True)  # (1, Q)
+    cum_col = jnp.sum(jnp.where(jj <= ii, a_row, 0.0), axis=1, keepdims=True)
+    cum_row = jnp.sum(jnp.where(ii <= jj, a, 0.0), axis=0, keepdims=True)
+    total = jnp.sum(a, axis=0, keepdims=True)  # (1, 1)
+
+    # intra-chunk: L[i,j] = exp(sum_{j<s<=i} a_s) for j <= i
+    L = jnp.where(ii >= jj, jnp.exp(cum_col - cum_row), 0.0)
     scores = (C @ B.T) * L  # (Q, Q)
     dtx = x * dt  # (Q, P)
     y = scores @ dtx  # (Q, P)
 
     # inter-chunk: contribution of the incoming state
-    decay_from_start = jnp.exp(a_cum)[:, None]  # (Q, 1)
-    y += (C * decay_from_start) @ state_ref[...].T  # (Q,N)@(N,P)
+    y += (C * jnp.exp(cum_col)) @ state_ref[...].T  # (Q,N)@(N,P)
 
     # state update: S = exp(sum a) * S_in + sum_s exp(a_cum[end]-a_cum[s]) dtx_s B_s
-    decay_to_end = jnp.exp(a_cum[-1] - a_cum)[:, None]  # (Q, 1)
-    new_state = (dtx * decay_to_end).T @ B  # (P, N)
-    state_ref[...] = jnp.exp(a_cum[-1]) * state_ref[...] + new_state
+    decay_to_end = jnp.exp(total - cum_col)  # (Q, 1)
+    new_state = jax.lax.dot_general(  # (dtx * decay)^T @ B: (P, N)
+        dtx * decay_to_end, B, (((0,), (0,)), ((), ())))
+    state_ref[...] = jnp.exp(total) * state_ref[...] + new_state
 
     y_ref[0] = y.astype(y_ref.dtype)
 
@@ -60,12 +65,12 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *, q):
 def ssd_scan_bhtpn(
     x: jax.Array,  # (BH, T, P)
     dt: jax.Array,  # (BH, T, 1) — post-softplus
-    a: jax.Array,  # (BH, 1) negative per-head decay rate
+    a: jax.Array,  # (BH, 1, 1) negative per-head decay rate
     b: jax.Array,  # (BH, T, N)
     c: jax.Array,  # (BH, T, N)
     *,
     q: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     bh, t, p = x.shape
     n = b.shape[2]
@@ -79,7 +84,7 @@ def ssd_scan_bhtpn(
         in_specs=[
             pl.BlockSpec((1, q, p), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, q, 1), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, q, n), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, q, n), lambda i, j: (i, j, 0)),
         ],

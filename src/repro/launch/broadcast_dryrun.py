@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 
 
 def run_one(arch: str, mesh_kind: str, schedule: str, n_blocks: int,
@@ -76,7 +75,7 @@ def run_one(arch: str, mesh_kind: str, schedule: str, n_blocks: int,
 
     body = partial(_bcast_body, axes=axes, dp=dp, schedule=schedule,
                    n_blocks=n_blocks, rounds_info=rounds_info)
-    fn = shard_map(body, mesh=mesh, in_specs=P("model"), out_specs=P("model"),
+    fn = jax.shard_map(body, mesh=mesh, in_specs=P("model"), out_specs=P("model"),
                    check_vma=False)
     t0 = time.time()
     lowered = jax.jit(fn, donate_argnums=(0,)).lower(buf_struct)

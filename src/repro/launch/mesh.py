@@ -5,7 +5,15 @@ touches jax device state — the dry-run sets XLA_FLAGS before first init.
 """
 from __future__ import annotations
 
-from repro.compat import make_mesh
+import jax
+
+
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...], **kwargs):
+    """``jax.make_mesh`` with Auto axis types, so sharding propagation works
+    as on a plain ``Mesh``."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes), **kwargs,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

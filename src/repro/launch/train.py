@@ -26,9 +26,11 @@ def main() -> None:
     args = ap.parse_args()
 
     from repro.configs import get_config, get_smoke
+    from repro.launch.runtime import setup_compile_cache
     from repro.optim.adamw import AdamWConfig
     from repro.train.loop import run_train
 
+    setup_compile_cache()
     cfg = get_config(args.arch) if args.full_config else get_smoke(args.arch)
     print(f"training {cfg.name}: ~{cfg.param_count()/1e6:.1f}M params, "
           f"{args.steps} steps")

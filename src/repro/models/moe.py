@@ -118,8 +118,6 @@ def apply_moe(p: PyTree, x: jax.Array, cfg) -> tuple[jax.Array, jax.Array]:
     if shardable:
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map
-
         def local_dispatch(xl, router):
             bufl, slotl, gatel, auxl, _ = _dispatch_combine_plan(
                 xl, router, m, t
@@ -129,7 +127,7 @@ def apply_moe(p: PyTree, x: jax.Array, cfg) -> tuple[jax.Array, jax.Array]:
 
         # slots stay LOCAL: each data shard owns its capacity slice of
         # every expert, so the combine gather below is shard-local too.
-        buf, slot, gate, aux = shard_map(
+        buf, slot, gate, aux = jax.shard_map(
             local_dispatch,
             mesh=mesh,
             in_specs=(P(dp_axes, None), P(None, None)),
@@ -158,7 +156,7 @@ def apply_moe(p: PyTree, x: jax.Array, cfg) -> tuple[jax.Array, jax.Array]:
         return jnp.einsum("tkd,tk->td", got, w)
 
     if shardable:
-        y = shard_map(
+        y = jax.shard_map(
             _combine,
             mesh=mesh,
             in_specs=(P(None, dp_axes, None), P(dp_axes, None),

@@ -65,6 +65,9 @@ class ServeEngine:
     # ------------------------------------------------------------------
     def start(self, ckpt: CheckpointManager, step: int, like: PyTree,
               *, lazy: bool = True) -> None:
+        """Restore step ``step`` of ``ckpt``; ``like`` gives the tree's
+        structure and may hold shapes (``jax.ShapeDtypeStruct``) only."""
+        self.params = None  # a restart does not keep the old tree alongside
         t0 = time.monotonic()
         if lazy:
             partial_params, finish, reader = ckpt.restore_lazy(
@@ -72,6 +75,9 @@ class ServeEngine:
             )
             t_first = time.monotonic() - t0
             first_bytes = reader.stats.fetched_compressed
+            # Nothing serves from the partial tree yet, so it goes before
+            # finish() builds the full one: one tree on the device, not two.
+            del partial_params
             self.params = finish()
             self.cold_start_stats = {
                 "t_first_leaves_s": t_first,

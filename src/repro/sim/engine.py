@@ -73,8 +73,9 @@ __all__ = [
 #: ``incremental`` (per-NIC dirty sets), which polices ``vector`` (flat
 #: numpy arrays, wide-front recompute), which in turn polices
 #: ``vector_jax`` (the same engine with the cap-chain min-kernel from
-#: ``repro.kernels.cap_chain`` on its wide fronts; falls back to the numpy
-#: path when jax is absent) — all differential-tested to produce
+#: ``repro.kernels.cap_chain`` on its wide fronts, run in the Pallas
+#: interpreter on the CPU; it refuses a TPU backend, whose compiler rejects
+#: the float64 kernel) — all differential-tested to produce
 #: bit-identical event logs and rates within 1e-9 (``tests/test_scale.py``,
 #: ``tests/test_vector_engine.py``).
 ENGINES = ("incremental", "vector", "vector_jax", "reference")

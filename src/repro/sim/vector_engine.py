@@ -1606,27 +1606,34 @@ class VectorJaxFlowSim(VectorFlowSim):
     min-cap chain through :func:`repro.kernels.cap_chain.cap_chain_rates`
     — a fused elementwise-minima kernel run in float64 (so its IEEE-754
     results are bit-identical to the numpy path; see the kernel module for
-    the dtype argument).  Narrow fronts keep the scalar fast path, and when
-    jax is unavailable the engine degrades gracefully to the plain numpy
-    wide fronts; ``jax_active`` records which happened.  Either way the
+    the dtype argument).  Narrow fronts keep the scalar fast path.  The
     event log is bit-identical to :class:`VectorFlowSim`, which stays the
     policing oracle for this tier exactly as the incremental engine polices
     the vector one.
+
+    The kernel runs in the Pallas interpreter, which is what the CPU
+    backend offers.  A TPU backend is refused: the v5e compiler rejects the
+    float64 kernel, and interpreting it there would hide the device.
     """
 
     def __init__(self, cfg: SimConfig | None = None, *, record_rates: bool = False):
-        super().__init__(cfg, record_rates=record_rates)
-        from repro.kernels.cap_chain import have_jax
+        import jax
 
-        self.jax_active = have_jax()
+        backend = jax.default_backend()
+        if backend != "cpu":
+            raise RuntimeError(
+                f"engine='vector_jax' runs its float64 pallas kernel in the "
+                f"Pallas interpreter on the CPU only; the backend is "
+                f"{backend!r}, and the TPU v5e compiler refuses 64-bit pallas "
+                f"kernels"
+            )
+        super().__init__(cfg, record_rates=record_rates)
         self.dispatch_stats["fronts_jax"] = 0
         self.dispatch_stats["flows_jax"] = 0
 
     def _front_rates(
         self, fids: np.ndarray, src: np.ndarray, dst: np.ndarray
     ) -> np.ndarray:
-        if not self.jax_active:
-            return super()._front_rates(fids, src, dst)
         from repro.kernels.cap_chain import cap_chain_rates
 
         cfg = self.cfg
@@ -1658,4 +1665,5 @@ class VectorJaxFlowSim(VectorFlowSim):
             in_cap=cfg.vm_nic.in_cap,
             decompress_rate=cfg.decompress_rate,
             block_size=cfg.block_size,
+            interpret=True,  # the CPU backend, checked in __init__
         )

@@ -16,33 +16,37 @@ os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
 import sys, json
 sys.path.insert(0, os.environ['REPRO_SRC'])
 import jax, jax.numpy as jnp, numpy as np
-from functools import partial
-from jax.sharding import PartitionSpec as P
-from repro.compat import make_mesh, shard_map
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.distributed.broadcast import (
-    tree_broadcast, faasnet_rounds, binomial_rounds, _bcast_body,
-    flatten_pytree, unflatten_pytree)
+    broadcast_fn, faasnet_rounds, flatten_pytree, root_rows, tree_broadcast)
 
 mesh = make_mesh((4, 2), ('data', 'model'))
 params = {'a': jnp.arange(640, dtype=jnp.float32).reshape(80, 8) / 1037.0,
           'b': jnp.arange(10, dtype=jnp.float32) * 0.05}
 flat, spec = flatten_pytree(params, pad_to=4)
+root = np.asarray(flat).view(np.uint16)
 out = {}
 
-# 1) schedule delivers root's bytes to every replica, from garbage
-for sched, info in [('binomial', binomial_rounds(4)),
-                    ('pipelined', faasnet_rounds(4, 4)),
-                    ('naive', None)]:
-    def corrupt_then_bcast(buf, sched=sched, info=info):
-        idx = jax.lax.axis_index(('data',))
-        buf = jnp.where(idx == 0, buf, -7.0)
-        return _bcast_body(buf, axes=('data',), dp=4, schedule=sched,
-                           n_blocks=4, rounds_info=info)
-    outs = shard_map(corrupt_then_bcast, mesh=mesh, in_specs=P(),
-                     out_specs=P('data'), check_vma=False)(
-        jnp.broadcast_to(flat, flat.shape))
-    ok = bool(jnp.allclose(outs.reshape(4, -1), flat[None], atol=0))
-    out[f'{sched}_correct'] = ok
+def delivered(res):
+    # every device's own shard holds the root's bytes, bit for bit
+    return len(res.addressable_shards) == 8 and all(
+        np.array_equal(np.asarray(sh.data).view(np.uint16), root)
+        for sh in res.addressable_shards)
+
+# 1) each replica passes its own buffer: garbage off the root, and zeros
+#    from root_rows; the schedule alone delivers the root's bytes
+for sched in ('binomial', 'pipelined', 'naive'):
+    garbage = np.concatenate(
+        [np.asarray(flat)] + [np.full(flat.shape, -7.0, flat.dtype)] * 3)
+    rows = jax.device_put(garbage, NamedSharding(mesh, P('data')))
+    fn = broadcast_fn(mesh, schedule=sched, n_blocks=4)
+    out[f'{sched}_correct'] = delivered(fn(rows))
+    out[f'{sched}_from_zeros'] = delivered(fn(root_rows(flat, mesh)))
+zero_rows = root_rows(flat, mesh)
+out['non_root_start_zero'] = all(
+    not np.asarray(sh.data).any()
+    for sh in zero_rows.addressable_shards if (sh.index[0].start or 0) != 0)
 
 # 2) end-to-end API: identity on replicated params + report sanity
 for sched in ('naive', 'allgather', 'binomial', 'pipelined'):
@@ -81,8 +85,10 @@ def results():
 
 
 def test_schedules_deliver_from_root(results):
+    assert results["non_root_start_zero"]
     for sched in ("binomial", "pipelined", "naive"):
         assert results[f"{sched}_correct"], sched
+        assert results[f"{sched}_from_zeros"], sched
 
 
 def test_identity_on_replicated(results):

@@ -115,3 +115,25 @@ def test_ssd_kernel_matches_model_chunked_path():
         np.asarray(y_model, np.float32), np.asarray(y_kernel, np.float32),
         atol=1e-3, rtol=1e-3,
     )
+
+
+@pytest.mark.parametrize("kernel", ["flash_attention", "decode_attention", "ssd_scan"])
+def test_kernels_refuse_cpu_unless_interpret_asked(kernel):
+    """No silent interpreter: off a TPU a kernel raises unless asked."""
+    k = jax.random.key(0)
+    if kernel == "flash_attention":
+        q = _rand(k, (1, 1, 128, 64), jnp.float32)
+        call = lambda **kw: ops.flash_attention(q, q, q, scale=0.125, **kw)
+    elif kernel == "decode_attention":
+        q = _rand(k, (1, 1, 1, 64), jnp.float32)
+        kv = _rand(k, (1, 1, 128, 64), jnp.float32)
+        valid = jnp.ones((128,), jnp.int32)
+        call = lambda **kw: ops.decode_attention(q, kv, kv, valid, scale=0.125, **kw)
+    else:
+        x = _rand(k, (1, 64, 1, 32), jnp.float32)
+        dt = jnp.full((1, 64, 1), 0.1)
+        bc = _rand(k, (1, 64, 1, 16), jnp.float32)
+        call = lambda **kw: ops.ssd_scan(x, dt, -jnp.ones((1,)), bc, bc, chunk=64, **kw)
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        call()
+    assert np.isfinite(np.asarray(call(interpret=True), np.float32)).all()

@@ -105,9 +105,9 @@ def rules():
     from repro.configs import get_config
     from repro.distributed.sharding import ShardingRules
 
-    from repro.compat import abstract_mesh
+    from jax.sharding import AbstractMesh
 
-    mesh = abstract_mesh((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     return ShardingRules(get_config("stablelm_12b"), mesh)
 
 

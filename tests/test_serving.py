@@ -56,3 +56,30 @@ def test_queue_drains_in_fifo_batches(ckpt):
         done += eng.step_batch()
     assert [r.rid for r in done] == ids
     assert all(r.t_done >= r.t_first_token >= r.t_submit for r in done)
+
+
+def test_serve_main_runs_in_process(tmp_path, monkeypatch):
+    from repro.launch import runtime, serve
+
+    # keep this test process's jax config as the suite set it
+    monkeypatch.setattr(runtime, "setup_compile_cache", lambda: None)
+    cs, done = serve.main(["--arch", "deepseek_7b", "--requests", "5",
+                           "--prompt-len", "8", "--max-new-tokens", "2",
+                           "--ckpt-dir", str(tmp_path)])
+    assert [len(r.out_tokens) for r in done] == [2] * 5
+    assert cs.engine.cfg.name == "deepseek_7b_smoke"
+    assert cs.manifest.raw_size == 4 * cs.engine.cfg.param_count()
+
+
+def test_serve_selects_the_one_chip_cut():
+    from repro.configs.deepseek_7b import CONFIG, ONE_CHIP, ONE_CHIP_CUT
+    from repro.launch.serve import select_config
+
+    cfg = select_config("deepseek_7b", "one_chip")
+    assert cfg is ONE_CHIP and cfg.attn_impl == "pallas"
+    # every width as published; only the depth is cut
+    for k in ("d_model", "n_heads", "n_kv_heads", "d_ff", "vocab_size", "hd"):
+        assert getattr(cfg, k) == getattr(CONFIG, k), k
+    assert ONE_CHIP_CUT["reduced"] == {"n_layers": (CONFIG.n_layers, cfg.n_layers)}
+    with pytest.raises(SystemExit):
+        select_config("mamba2_130m", "one_chip")

@@ -11,8 +11,8 @@ incremental engine was:
     exactly;
   * against the reference oracle it must agree to ±1e-9 on completion
     times and peak egress, like the incremental engine does;
-  * the ``vector_jax`` tier (fused pallas cap-chain kernel; numpy fallback
-    when jax is absent) must be bit-identical to ``vector`` — and both
+  * the ``vector_jax`` tier (fused pallas cap-chain kernel, run in the
+    Pallas interpreter) must be bit-identical to ``vector`` — and both
     must be cutoff-invariant: forcing every ready front down the wide
     vectorized/pallas path (``vector_scalar_cutoff=0``) may not change a
     single bit.
@@ -39,7 +39,6 @@ from repro.core.topology import (
     kraken_plan,
     on_demand_plan,
 )
-from repro.kernels.cap_chain import have_jax
 from repro.sim import ScaleConfig, WaveConfig, provision_wave, run_scale
 from repro.sim.engine import ENGINES, FlowSim, SimConfig, make_sim
 from repro.sim.reference import ReferenceFlowSim
@@ -108,7 +107,7 @@ def _assert_four_way(plan, cfg: SimConfig, *, slow_vms=None):
     single event, so a batch of same-instant completions can take a
     microscopically different arithmetic path.  Both vector tiers are also
     re-run with ``vector_scalar_cutoff=0`` so every ready front takes the
-    wide vectorized (resp. pallas, when jax is present) path — the cutoff
+    wide vectorized (resp. pallas) path — the cutoff
     is a pure performance knob and may not change a single bit.
     """
     inc, inc_states = _run_engine(FlowSim, plan, cfg, slow_vms=slow_vms)
@@ -132,10 +131,9 @@ def _assert_four_way(plan, cfg: SimConfig, *, slow_vms=None):
     assert s0["fronts_scalar"] == 0 and s0["flows_scalar"] == 0
     assert s0["fronts_vector"] == s["fronts_scalar"] + s["fronts_vector"]
     assert s0["front_width_hist"] == s["front_width_hist"]
-    if jx0.jax_active:
-        # with jax present, every wide front went through the pallas kernel
-        assert jx0.dispatch_stats["fronts_jax"] == s0["fronts_vector"]
-        assert jx0.dispatch_stats["flows_jax"] == s0["flows_vector"]
+    # every wide front went through the pallas kernel
+    assert jx0.dispatch_stats["fronts_jax"] == s0["fronts_vector"]
+    assert jx0.dispatch_stats["flows_jax"] == s0["flows_vector"]
 
     # vector vs reference: 1e-9 completion times + peak egress
     assert _close(vec.now, ref.now)
@@ -266,8 +264,17 @@ def test_make_sim_selects_backend():
     jx = make_sim(SimConfig(engine="vector_jax"))
     assert isinstance(jx, VectorJaxFlowSim)
     assert isinstance(jx, VectorFlowSim)  # subclass: shares the whole engine
-    assert jx.jax_active == have_jax()  # graceful numpy fallback otherwise
+    assert jx.dispatch_stats["fronts_jax"] == 0  # kernel fronts, counted from the start
     assert set(ENGINES) == {"incremental", "vector", "vector_jax", "reference"}
+
+
+def test_vector_jax_refuses_tpu_backend(monkeypatch):
+    """The float64 kernel does not compile for a TPU; no silent interpreter."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="refuses 64-bit pallas"):
+        make_sim(SimConfig(engine="vector_jax"))
 
 
 def test_make_sim_rejects_unknown_engine():
