@@ -36,6 +36,7 @@ from repro.core.blockstore import (
     BlockReader,
     write_blockstore,
 )
+from repro.tracing import span
 
 PyTree = Any
 
@@ -162,10 +163,31 @@ class CheckpointManager:
         return doc, metas
 
     def _decode(self, meta: LeafMeta, raw: bytes):
-        if meta.dtype == "bfloat16":
-            arr = np.frombuffer(raw, np.uint16).reshape(meta.shape)
-            return jnp.asarray(arr.view(jnp.bfloat16))
-        return jnp.asarray(np.frombuffer(raw, np.dtype(meta.dtype)).reshape(meta.shape))
+        """One leaf from its raw bytes, on the device."""
+        with span("restore.decode", bytes=meta.nbytes):
+            if meta.dtype == "bfloat16":
+                arr = np.frombuffer(raw, np.uint16).reshape(meta.shape).view(jnp.bfloat16)
+            else:
+                arr = np.frombuffer(raw, np.dtype(meta.dtype)).reshape(meta.shape)
+        with span("restore.h2d", bytes=meta.nbytes):
+            return jnp.asarray(arr)
+
+    def _load(self, reader: BlockReader, metas: list[LeafMeta], like: PyTree,
+              pred: Callable[[str], bool], pass_name: str) -> PyTree:
+        """The tree of ``like`` with the leaves whose path matches ``pred``
+        read through ``reader`` and the others zero (span ``restore.pass``)."""
+        by_path = {m.path: m for m in metas}
+        chosen = [(by_path[path], pred(path)) for path, _ in _leaf_paths(like)]
+        n = sum(take for _, take in chosen)
+        with span("restore.pass", **{"pass": pass_name}, leaves=n,
+                  zero_leaves=len(chosen) - n,
+                  bytes=sum(m.nbytes for m, take in chosen if take)):
+            leaves = [
+                self._decode(m, reader.read_range(m.offset, m.nbytes)) if take
+                else jnp.zeros(m.shape, jnp.bfloat16 if m.dtype == "bfloat16" else m.dtype)
+                for m, take in chosen
+            ]
+        return jax.tree.unflatten(jax.tree.structure(like), leaves)
 
     def block_manifest(self, step: int) -> BlockManifest:
         """Codec, raw size and compressed block offsets of checkpoint ``step``."""
@@ -174,14 +196,9 @@ class CheckpointManager:
 
     def restore(self, step: int, like: PyTree) -> PyTree:
         """Full restore into the structure of ``like`` (arrays or shapes)."""
-        doc, metas = self._load_manifest(step)
+        _, metas = self._load_manifest(step)
         with BlockReader(self._paths(step)[0]) as reader:
-            by_path = {m.path: m for m in metas}
-            leaves = []
-            for path, leaf in _leaf_paths(like):
-                m = by_path[path]
-                leaves.append(self._decode(m, reader.read_range(m.offset, m.nbytes)))
-        return jax.tree.unflatten(jax.tree.structure(like), leaves)
+            return self._load(reader, metas, like, lambda p: True, "full")
 
     def restore_lazy(
         self,
@@ -194,27 +211,12 @@ class CheckpointManager:
         Returns (partial tree with zeros elsewhere, finish() to complete it,
         reader for fetch statistics).  ``finish()`` returns the full tree.
         """
-        doc, metas = self._load_manifest(step)
+        _, metas = self._load_manifest(step)
         reader = BlockReader(self._paths(step)[0])
-        by_path = {m.path: m for m in metas}
-        tdef = jax.tree.structure(like)
-        pairs = _leaf_paths(like)
-
-        def load(pred):
-            ls = []
-            for path, leaf in pairs:
-                m = by_path[path]
-                if pred(path):
-                    ls.append(self._decode(m, reader.read_range(m.offset, m.nbytes)))
-                else:
-                    ls.append(jnp.zeros(m.shape, jnp.dtype(
-                        jnp.bfloat16 if m.dtype == "bfloat16" else m.dtype)))
-            return jax.tree.unflatten(tdef, ls)
-
-        partial_tree = load(first)
+        partial_tree = self._load(reader, metas, like, first, "first")
 
         def finish() -> PyTree:
-            return load(lambda p: True)
+            return self._load(reader, metas, like, lambda p: True, "finish")
 
         return partial_tree, finish, reader
 

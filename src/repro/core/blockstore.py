@@ -37,6 +37,8 @@ import struct
 import zlib
 from dataclasses import dataclass, field
 
+from repro.tracing import span
+
 try:  # optional: zstd is the production codec, zlib the stdlib fallback
     import zstandard as _zstd
 except ImportError:  # pragma: no cover - exercised on bare interpreters
@@ -273,12 +275,13 @@ class BlockReader:
         except Exception:
             pass
 
-    def _read_at(self, data_offset: int, size: int) -> bytes:
+    def _read_at(self, data_offset: int, size: int, blocks: int = 1) -> bytes:
         if self._f is None:
             raise ValueError(f"BlockReader for {self.path} is closed")
-        self._f.seek(self._data_start + data_offset)
-        self.file_reads += 1
-        return self._f.read(size)
+        with span("blockstore.read", bytes=size, blocks=blocks):
+            self._f.seek(self._data_start + data_offset)
+            self.file_reads += 1
+            return self._f.read(size)
 
     # -- block-level -----------------------------------------------------
     def fetch_block_compressed(self, i: int) -> bytes:
@@ -311,10 +314,12 @@ class BlockReader:
             j = i
             while j + 1 <= last and (j + 1) not in self._cache:
                 j += 1
-            span = self._read_at(m.offsets[i], m.offsets[j + 1] - m.offsets[i])
+            run = self._read_at(m.offsets[i], m.offsets[j + 1] - m.offsets[i], j + 1 - i)
             base = m.offsets[i]
-            for k in range(i, j + 1):
-                self._ingest(k, span[m.offsets[k] - base : m.offsets[k + 1] - base])
+            with span("blockstore.decompress", blocks=j + 1 - i) as sp:
+                sp.set(raw_bytes=sum(
+                    len(self._ingest(k, run[m.offsets[k] - base : m.offsets[k + 1] - base]))
+                    for k in range(i, j + 1)))
             i = j + 1
 
     # -- range-level (on-demand I/O) --------------------------------------
@@ -328,15 +333,18 @@ class BlockReader:
             )
         self.stats.useful_bytes += length
         first, last = m.block_range_for(offset, length)
-        if first <= last:
-            self._fetch_run(first, last)
-        out = io.BytesIO()
-        for i in range(first, last + 1):
-            raw = self.get_block(i)
-            lo = max(0, offset - i * m.block_size)
-            hi = min(len(raw), offset + length - i * m.block_size)
-            out.write(raw[lo:hi])
-        return out.getvalue()
+        cached = sum(1 for i in range(first, last + 1) if i in self._cache)
+        with span("blockstore.read_range", bytes=length, blocks=last + 1 - first,
+                  blocks_cached=cached):
+            if first <= last:
+                self._fetch_run(first, last)
+            out = io.BytesIO()
+            for i in range(first, last + 1):
+                raw = self.get_block(i)
+                lo = max(0, offset - i * m.block_size)
+                hi = min(len(raw), offset + length - i * m.block_size)
+                out.write(raw[lo:hi])
+            return out.getvalue()
 
     def read_all(self) -> bytes:
         return self.read_range(0, self.manifest.raw_size)

@@ -97,8 +97,14 @@ def main(argv: list[str] | None = None) -> tuple[ColdStart, list]:
     print(f"cold start (lazy): first weights {s['t_first_leaves_s']*1e3:.0f} ms, "
           f"full {s['t_full_s']*1e3:.0f} ms, "
           f"amplification {s['read_amplification']:.2f}x")
+    print(f"restore: read {s['read_s']*1e3:.0f} ms, decompress "
+          f"{s['decompress_s']*1e3:.0f} ms ({s['blocks_decompressed']} blocks, "
+          f"{s['blocks_from_cache']} from cache), decode {s['decode_s']*1e3:.0f} ms, "
+          f"host-to-device {s['h2d_s']*1e3:.0f} ms ({s['h2d_bytes']/1e6:.1f} MB)")
     done = serve_requests(cs.engine, n=args.requests, prompt_len=args.prompt_len,
                           max_new_tokens=args.max_new_tokens, seed=0)
+    print(f"cold start to the first batch's prefill: {s['traces']} traces, "
+          f"{s['cache_loads']} executables loaded from the persistent cache")
     lat = [(r.t_done - r.t_submit) * 1e3 for r in done]
     print(f"served {len(done)} requests; latency mean {np.mean(lat):.0f} ms, "
           f"p99 {np.percentile(lat, 99):.0f} ms")

@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.models import model_for
+from repro.tracing import span, total
 
 PyTree = Any
 
@@ -59,6 +60,7 @@ class ServeEngine:
         self.done: list[Request] = []
         self.cold_start_stats: dict = {}
         self._rid = 0
+        self._first_batch_pending = False
 
     # ------------------------------------------------------------------
     # Cold start (paper §3.5 on-demand I/O applied to a model checkpoint)
@@ -66,29 +68,40 @@ class ServeEngine:
     def start(self, ckpt: CheckpointManager, step: int, like: PyTree,
               *, lazy: bool = True) -> None:
         """Restore step ``step`` of ``ckpt``; ``like`` gives the tree's
-        structure and may hold shapes (``jax.ShapeDtypeStruct``) only."""
+        structure and may hold shapes (``jax.ShapeDtypeStruct``) only.
+
+        ``cold_start_stats`` then holds what the restore's spans counted:
+        its host time by stage (``read_s``, ``decompress_s``, ``decode_s``
+        for block joins and leaf decode, ``h2d_s``), ``h2d_bytes``,
+        ``blocks_decompressed``, ``blocks_from_cache``, and ``traces`` and
+        ``cache_loads``, to which the first batch's prefill adds its own.
+        A lazy start adds the first pass's time (``t_first_leaves_s``),
+        compressed bytes and read amplification."""
         self.params = None  # a restart does not keep the old tree alongside
-        t0 = time.monotonic()
-        if lazy:
-            partial_params, finish, reader = ckpt.restore_lazy(
-                step, like, FIRST_LEAF_PRED
-            )
-            t_first = time.monotonic() - t0
-            first_bytes = reader.stats.fetched_compressed
-            # Nothing serves from the partial tree yet, so it goes before
-            # finish() builds the full one: one tree on the device, not two.
-            del partial_params
-            self.params = finish()
-            self.cold_start_stats = {
-                "t_first_leaves_s": t_first,
-                "t_full_s": time.monotonic() - t0,
-                "first_fetch_compressed_bytes": first_bytes,
-                "total_fetch_compressed_bytes": reader.stats.fetched_compressed,
-                "read_amplification": reader.stats.amplification(),
-            }
-        else:
-            self.params = ckpt.restore(step, like)
-            self.cold_start_stats = {"t_full_s": time.monotonic() - t0}
+        first = {}
+        with span("restore", lazy=lazy) as root:
+            if lazy:
+                partial_params, finish, reader = ckpt.restore_lazy(
+                    step, like, FIRST_LEAF_PRED
+                )
+                first = {
+                    "t_first_leaves_s": root.totals[("restore.pass", "seconds")],
+                    "first_fetch_compressed_bytes": reader.stats.fetched_compressed,
+                    "read_amplification": reader.stats.amplification(),
+                }
+                # Nothing serves from the partial tree yet, so it goes before
+                # finish() builds the full one: one tree on the device, not two.
+                del partial_params
+                self.params = finish()
+                first["total_fetch_compressed_bytes"] = reader.stats.fetched_compressed
+                # The reader's block cache holds the whole raw payload: it is
+                # freed here, inside the span, not when this call returns.
+                reader.close()
+                del finish, reader
+            else:
+                self.params = ckpt.restore(step, like)
+        self.cold_start_stats = {**first, **_restore_stats(root.totals)}
+        self._first_batch_pending = True
 
     def set_params(self, params: PyTree) -> None:
         self.params = params
@@ -111,30 +124,63 @@ class ServeEngine:
             return []
         t = max(len(r.prompt) for r in batch_reqs)
         b = len(batch_reqs)
-        toks = np.zeros((b, t), np.int32)
-        for i, r in enumerate(batch_reqs):
-            toks[i, t - len(r.prompt):] = r.prompt  # left-pad
-        budget = max(r.max_new_tokens for r in batch_reqs)
-        cache_len = t + budget
-        logits, cache = self.model.prefill(
-            self.params, {"tokens": jnp.asarray(toks)}, cache_len=cache_len
-        )
-        last = jnp.argmax(logits[:, -1], axis=-1)
-        now = time.monotonic()
-        for i, r in enumerate(batch_reqs):
-            r.out_tokens.append(int(last[i]))
-            r.t_first_token = now
-        for k in range(1, budget):
-            batch_in = {
-                "tokens": last[:, None].astype(jnp.int32),
-                "pos": jnp.asarray(t + k - 1, jnp.int32),
-            }
-            logits, cache = self.model.decode_step(self.params, batch_in, cache)
-            last = jnp.argmax(logits[:, -1], axis=-1)
+        with span("serve.step_batch", batch=b, tokens=b * t):
+            toks = np.zeros((b, t), np.int32)
             for i, r in enumerate(batch_reqs):
-                if len(r.out_tokens) < r.max_new_tokens:
+                toks[i, t - len(r.prompt):] = r.prompt  # left-pad
+            budget = max(r.max_new_tokens for r in batch_reqs)
+            cache_len = t + budget
+            with span("serve.prefill") as prefill:
+                logits, cache = self.model.prefill(
+                    self.params, {"tokens": jnp.asarray(toks)}, cache_len=cache_len
+                )
+            with span("serve.first_token"):
+                last = jnp.argmax(logits[:, -1], axis=-1)
+                for i, r in enumerate(batch_reqs):
                     r.out_tokens.append(int(last[i]))
+                now = time.monotonic()
+                for r in batch_reqs:
+                    r.t_first_token = now
+            if self._first_batch_pending:
+                self._first_batch_pending = False
+                for key in ("traces", "cache_loads"):
+                    self.cold_start_stats[key] += prefill.counts.get(key, 0)
+            for k in range(1, budget):
+                batch_in = {
+                    "tokens": last[:, None].astype(jnp.int32),
+                    "pos": jnp.asarray(t + k - 1, jnp.int32),
+                }
+                with span("serve.decode"):
+                    logits, cache = self.model.decode_step(self.params, batch_in, cache)
+                last = jnp.argmax(logits[:, -1], axis=-1)
+                for i, r in enumerate(batch_reqs):
+                    if len(r.out_tokens) < r.max_new_tokens:
+                        r.out_tokens.append(int(last[i]))
         for r in batch_reqs:
             r.t_done = time.monotonic()
         self.done += batch_reqs
         return batch_reqs
+
+
+def _restore_stats(totals: dict) -> dict:
+    """A restore's counters from its root span's ``totals``
+    (``repro.tracing``)."""
+
+    def t(name, key):
+        return totals.get((name, key), 0)
+
+    read, decompress = t("blockstore.read", "seconds"), t("blockstore.decompress", "seconds")
+    return {
+        "t_full_s": t("restore.pass", "seconds"),
+        "read_s": read,
+        "decompress_s": decompress,
+        # the block joins in read_range, then the leaf decode
+        "decode_s": (t("blockstore.read_range", "seconds") - read - decompress
+                     + t("restore.decode", "seconds")),
+        "h2d_s": t("restore.h2d", "seconds"),
+        "h2d_bytes": t("restore.h2d", "bytes"),
+        "blocks_decompressed": t("blockstore.decompress", "blocks"),
+        "blocks_from_cache": t("blockstore.read_range", "blocks_cached"),
+        "traces": total(totals, "traces"),
+        "cache_loads": total(totals, "cache_loads"),
+    }
