@@ -162,28 +162,36 @@ class CheckpointManager:
         metas = [LeafMeta(**{**m, "shape": tuple(m["shape"])}) for m in doc["leaves"]]
         return doc, metas
 
-    def _decode(self, meta: LeafMeta, raw: bytes):
-        """One leaf from its raw bytes, on the device."""
+    def _decode(self, meta: LeafMeta, reader: BlockReader):
+        """One leaf, on the device: a fresh host array of the leaf's shape
+        and dtype (``uint16`` storage for ``bfloat16``) is filled in place
+        from its blocks, then uploaded. The array is never reused: the
+        upload may alias it (CPU) or copy it later (TPU)."""
         with span("restore.decode", bytes=meta.nbytes):
-            if meta.dtype == "bfloat16":
-                arr = np.frombuffer(raw, np.uint16).reshape(meta.shape).view(jnp.bfloat16)
-            else:
-                arr = np.frombuffer(raw, np.dtype(meta.dtype)).reshape(meta.shape)
+            bf16 = meta.dtype == "bfloat16"
+            host = np.empty(meta.shape, np.uint16 if bf16 else np.dtype(meta.dtype))
+            arr = host.view(jnp.bfloat16) if bf16 else host
+        reader.read_range_into(meta.offset, meta.nbytes, host)
         with span("restore.h2d", bytes=meta.nbytes):
             return jnp.asarray(arr)
 
     def _load(self, reader: BlockReader, metas: list[LeafMeta], like: PyTree,
-              pred: Callable[[str], bool], pass_name: str) -> PyTree:
-        """The tree of ``like`` with the leaves whose path matches ``pred``
-        read through ``reader`` and the others zero (span ``restore.pass``)."""
+              pred: Callable[[str], bool], pass_name: str,
+              have: dict[str, Any] | None = None) -> PyTree:
+        """The tree of ``like``: leaves in ``have`` (path -> array) as they
+        are, those whose path matches ``pred`` read through ``reader``, the
+        others zero (span ``restore.pass``)."""
+        have = have or {}
         by_path = {m.path: m for m in metas}
-        chosen = [(by_path[path], pred(path)) for path, _ in _leaf_paths(like)]
+        chosen = [(by_path[path], path not in have and pred(path))
+                  for path, _ in _leaf_paths(like)]
         n = sum(take for _, take in chosen)
         with span("restore.pass", **{"pass": pass_name}, leaves=n,
-                  zero_leaves=len(chosen) - n,
+                  zero_leaves=len(chosen) - n - len(have),
                   bytes=sum(m.nbytes for m, take in chosen if take)):
             leaves = [
-                self._decode(m, reader.read_range(m.offset, m.nbytes)) if take
+                have[m.path] if m.path in have
+                else self._decode(m, reader) if take
                 else jnp.zeros(m.shape, jnp.bfloat16 if m.dtype == "bfloat16" else m.dtype)
                 for m, take in chosen
             ]
@@ -209,14 +217,19 @@ class CheckpointManager:
         """On-demand restore (paper §3.5): load leaves matching ``first`` now.
 
         Returns (partial tree with zeros elsewhere, finish() to complete it,
-        reader for fetch statistics).  ``finish()`` returns the full tree.
+        reader for fetch statistics).  ``finish()`` returns the full tree:
+        it loads only the leaves ``first`` did not take and passes the first
+        pass's device arrays into the tree as they are, so each leaf is
+        read and uploaded once. When ``first`` took every leaf it reads
+        nothing, though its ``restore.pass`` span still opens.
         """
         _, metas = self._load_manifest(step)
         reader = BlockReader(self._paths(step)[0])
         partial_tree = self._load(reader, metas, like, first, "first")
+        loaded = {p: leaf for p, leaf in _leaf_paths(partial_tree) if first(p)}
 
         def finish() -> PyTree:
-            return self._load(reader, metas, like, lambda p: True, "finish")
+            return self._load(reader, metas, like, lambda p: True, "finish", loaded)
 
         return partial_tree, finish, reader
 

@@ -31,7 +31,6 @@ The format is used by three layers:
 """
 from __future__ import annotations
 
-import io
 import os
 import struct
 import zlib
@@ -245,6 +244,12 @@ class BlockReader:
     cold sequential range costs one syscall instead of one per block.
     ``stats`` accounting is unchanged: the same per-block useful/fetched
     byte and block counts as the one-read-per-block implementation.
+
+    :meth:`read_range_into` assembles a range in place: each covering
+    block's bytes are copied once, straight into the caller's buffer (a
+    restored leaf's own host array), with no join buffer between.
+    :meth:`read_range` is the same routine into a fresh buffer, returned
+    as ``bytes``.
     """
 
     def __init__(self, path: str, manifest: BlockManifest | None = None) -> None:
@@ -323,7 +328,7 @@ class BlockReader:
             i = j + 1
 
     # -- range-level (on-demand I/O) --------------------------------------
-    def read_range(self, offset: int, length: int) -> bytes:
+    def _check_range(self, offset: int, length: int) -> None:
         m = self.manifest
         if length < 0:
             raise ValueError(f"negative read length {length}")
@@ -331,6 +336,22 @@ class BlockReader:
             raise ValueError(
                 f"range [{offset}, {offset + length}) outside payload of {m.raw_size}"
             )
+
+    def read_range(self, offset: int, length: int) -> bytes:
+        self._check_range(offset, length)
+        out = bytearray(length)
+        self.read_range_into(offset, length, out)
+        return bytes(out)
+
+    def read_range_into(self, offset: int, length: int, out) -> None:
+        """Raw range [offset, offset+length) into ``out``, a writable
+        C-contiguous buffer of exactly ``length`` bytes; each covering
+        block's bytes are copied once, into their place."""
+        self._check_range(offset, length)
+        dst = memoryview(out).cast("B")
+        if dst.readonly or dst.nbytes != length:
+            raise ValueError(f"need a writable buffer of {length} bytes")
+        m = self.manifest
         self.stats.useful_bytes += length
         first, last = m.block_range_for(offset, length)
         cached = sum(1 for i in range(first, last + 1) if i in self._cache)
@@ -338,13 +359,13 @@ class BlockReader:
                   blocks_cached=cached):
             if first <= last:
                 self._fetch_run(first, last)
-            out = io.BytesIO()
+            pos = 0
             for i in range(first, last + 1):
-                raw = self.get_block(i)
+                raw = self._cache[i]
                 lo = max(0, offset - i * m.block_size)
                 hi = min(len(raw), offset + length - i * m.block_size)
-                out.write(raw[lo:hi])
-            return out.getvalue()
+                dst[pos : pos + hi - lo] = memoryview(raw)[lo:hi]
+                pos += hi - lo
 
     def read_all(self) -> bytes:
         return self.read_range(0, self.manifest.raw_size)

@@ -89,8 +89,9 @@ class ServeEngine:
                     "first_fetch_compressed_bytes": reader.stats.fetched_compressed,
                     "read_amplification": reader.stats.amplification(),
                 }
-                # Nothing serves from the partial tree yet, so it goes before
-                # finish() builds the full one: one tree on the device, not two.
+                # Nothing serves from the partial tree yet. Its loaded leaves
+                # pass into the full tree finish() builds, and its zeros go
+                # before finish() loads the rest: one tree on the device.
                 del partial_params
                 self.params = finish()
                 first["total_fetch_compressed_bytes"] = reader.stats.fetched_compressed

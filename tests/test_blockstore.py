@@ -6,10 +6,11 @@ absent; deterministic example-based equivalents always run.
 import os
 import random
 
+import numpy as np
 import pytest
 
 from repro.core import BlockReader, read_manifest, write_blockstore
-from repro.core.blockstore import default_codec, have_zstd
+from repro.core.blockstore import ReadStats, default_codec, have_zstd
 
 try:
     from hypothesis import given, settings
@@ -337,3 +338,62 @@ def test_reader_close_and_context_manager(tmp_path):
     with pytest.raises(ValueError):
         r.fetch_block_compressed(0)  # closed handle refuses cleanly
     r.close()  # idempotent
+
+
+# ----------------------------------------------------------------------
+# In-place assembly: read_range_into
+# ----------------------------------------------------------------------
+BS = 4096
+INTO_PAYLOAD = random.Random(11).randbytes(10 * BS + 123)
+INTO_RANGES = {
+    "inside_one_block": (BS + 100, 500),
+    "across_block_edges": (BS - 7, 2 * BS + 14),
+    "on_block_boundaries": (2 * BS, 3 * BS),
+    "zero_length": (3 * BS + 5, 0),
+    "whole_payload": (0, len(INTO_PAYLOAD)),
+}
+
+
+@pytest.fixture
+def into_path(tmp_path):
+    path = str(tmp_path / "p.blocks")
+    write_blockstore(INTO_PAYLOAD, path, block_size=BS)
+    return path
+
+
+@pytest.mark.parametrize("cached", [False, True], ids=["cold", "cached"])
+@pytest.mark.parametrize("case", list(INTO_RANGES))
+def test_read_range_into_matches_read_range(into_path, case, cached):
+    off, ln = INTO_RANGES[case]
+    r = BlockReader(into_path)
+    if cached:
+        r.read_all()
+    out = np.empty(ln, np.uint8)
+    r.read_range_into(off, ln, out)
+    assert out.tobytes() == INTO_PAYLOAD[off : off + ln]
+    assert r.read_range(off, ln) == INTO_PAYLOAD[off : off + ln]
+
+
+def test_read_range_into_fills_a_leaf_array(into_path):
+    r = BlockReader(into_path)
+    leaf = np.empty((3, 5), np.float32)  # 60 bytes across the first block edge
+    r.read_range_into(BS - 30, leaf.nbytes, leaf)
+    assert leaf.tobytes() == INTO_PAYLOAD[BS - 30 : BS + 30]
+
+
+def test_read_range_into_accounts_as_read_range(into_path):
+    a, b = BlockReader(into_path), BlockReader(into_path)
+    for r in (a, b):
+        r.get_block(5)  # a cached hole splits the coalesced reads
+    for off, ln in [*INTO_RANGES.values(), (BS + 1, 6 * BS)]:
+        a.read_range(off, ln)
+        b.read_range_into(off, ln, bytearray(ln))
+        assert b.stats == a.stats and b.file_reads == a.file_reads, (off, ln)
+
+
+def test_read_range_into_rejects_a_wrong_buffer(into_path):
+    r = BlockReader(into_path)
+    for out in (bytearray(99), bytearray(101), np.empty(26, np.float32), bytes(100)):
+        with pytest.raises(ValueError):
+            r.read_range_into(0, 100, out)
+    assert r.stats == ReadStats() and r.file_reads == 0  # nothing fetched

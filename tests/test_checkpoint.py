@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import tracing
 from repro.checkpoint.manager import CheckpointManager
 
 
@@ -83,6 +84,38 @@ def test_lazy_restore_reads_fewer_bytes(tmp_path):
     full = finish()
     assert _equal(t, full)
     assert reader.stats.fetched_compressed > first_bytes
+
+
+def test_lazy_finish_reads_nothing_when_the_first_pass_took_every_leaf(tmp_path):
+    mgr = CheckpointManager(str(tmp_path), block_size=4096)
+    t = _tree()
+    mgr.save(1, t)
+    partial, finish, reader = mgr.restore_lazy(1, t, first=lambda p: True)
+    stats, reads = (reader.stats.blocks_fetched, reader.stats.useful_bytes), reader.file_reads
+    with tracing.span("finish") as sp:
+        full = finish()
+    assert (reader.stats.blocks_fetched, reader.stats.useful_bytes) == stats
+    assert reader.file_reads == reads
+    assert ("blockstore.read_range", "seconds") not in sp.totals
+    assert sp.totals.get(("restore.h2d", "bytes"), 0) == 0
+    assert sp.totals[("restore.pass", "leaves")] == 0
+    assert all(x is y for x, y in zip(jax.tree.leaves(partial), jax.tree.leaves(full)))
+    assert _equal(t, full)
+
+
+def test_lazy_finish_loads_only_the_leaves_the_first_pass_left(tmp_path):
+    mgr = CheckpointManager(str(tmp_path), block_size=4096)
+    t = _tree()
+    mgr.save(1, t)
+    partial, finish, reader = mgr.restore_lazy(1, t, first=lambda p: p.startswith("embed"))
+    with tracing.span("finish") as sp:
+        full = finish()
+    raw = mgr.block_manifest(1).raw_size
+    embed = t["embed"]["table"].nbytes
+    assert sp.totals[("restore.h2d", "bytes")] == raw - embed
+    assert reader.stats.useful_bytes == raw  # each leaf read once in all
+    assert full["embed"]["table"] is partial["embed"]["table"]
+    assert _equal(t, full)
 
 
 def test_iter_blocks_covers_payload(tmp_path):

@@ -113,10 +113,11 @@ def test_counts_in_the_trace(traced, ckpt):
     eng, _, spans, _ = traced
     mgr, like = ckpt
     manifest = mgr.block_manifest(0)
-    # FIRST_LEAF_PRED takes every leaf of this model: each is uploaded twice
+    # FIRST_LEAF_PRED takes every leaf of this model: the first pass uploads
+    # each once, and finish() reuses them
     _, metas = mgr._load_manifest(0)
     assert all(FIRST_LEAF_PRED(m.path) for m in metas)
-    assert args_sum(spans, "restore.h2d", "bytes") == 2 * manifest.raw_size
+    assert args_sum(spans, "restore.h2d", "bytes") == manifest.raw_size
     assert args_sum(spans, "blockstore.decompress", "blocks") == manifest.n_blocks
     assert args_sum(spans, "blockstore.decompress", "raw_bytes") == manifest.raw_size
     assert args_sum(spans, "blockstore.read", "bytes") == manifest.compressed_size()
