@@ -92,7 +92,21 @@ class ModelConfig:
     rope_pct: float = 1.0
     tie_embeddings: bool = False
     embed_scale: bool = False  # gemma: scale embeddings by sqrt(d)
+    norm_eps: float = 1e-6  # every RMSNorm/LayerNorm, the gated one included
+    # muP-style constants (Granite): embeddings times embedding_multiplier,
+    # each residual branch times residual_multiplier, logits divided by
+    # logits_scaling; attention_multiplier is the softmax scale (None:
+    # 1/sqrt(head_dim))
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: Optional[float] = None
+    # a bias on each Mamba-2 causal conv (x, B and C channels). Kept here and
+    # not on SSMConfig, whose fields a benchmark configuration file states
+    # as one group
+    ssm_conv_bias: bool = False
     # numerics / impl
+    param_dtype: str = "float32"  # dtype of the initialised (and stored) tree
     compute_dtype: str = "bfloat16"
     attn_impl: str = "chunked"  # full | chunked | pallas
     attn_chunk: int = 1024
@@ -135,39 +149,46 @@ class ModelConfig:
         return specs
 
     def param_count(self) -> int:
-        """Analytic parameter count (embedding + blocks + head)."""
+        """Parameters in the tree ``repro.models.model_for(cfg).init`` builds."""
         d, hd = self.d_model, self.hd
+        norm = d * (2 if self.norm == "layernorm" else 1)
+        attn = d * (self.n_heads + 2 * self.n_kv_heads) * hd + self.n_heads * hd * d
+        if self.attn_bias:
+            attn += (self.n_heads + 2 * self.n_kv_heads) * hd + d
+        if self.qk_norm:
+            attn += 2 * hd
+
+        def mlp(width: int, bias: bool = False) -> int:
+            n = (3 if self.act in ("swiglu", "geglu") else 2) * d * width
+            return n + (width + d if bias else 0)
+
         n = self.vocab_size * d  # embedding
         if not self.tie_embeddings:
             n += self.vocab_size * d
         for spec in self.layer_specs():
-            n += d  # norm1
+            n += norm  # norm1
             if spec.mixer == "attn":
-                n += d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd
-                n += self.n_heads * hd * d
+                n += attn
             else:
                 s = self.ssm
                 hp = s.n_heads * s.head_dim
+                conv_ch = hp + 2 * s.n_groups * s.d_state
                 n += 2 * d * hp + 2 * d * s.n_groups * s.d_state + d * s.n_heads
-                n += s.conv_width * (hp + 2 * s.n_groups * s.d_state)
+                n += s.conv_width * conv_ch + (conv_ch if self.ssm_conv_bias else 0)
                 n += hp * d + hp + 3 * s.n_heads
             if spec.ffn == "mlp":
-                n += d  # norm2
-                mult = 3 if self.act in ("swiglu", "geglu") else 2
-                n += mult * d * self.d_ff
+                n += norm + mlp(self.d_ff, self.mlp_bias)
             elif spec.ffn == "moe":
-                n += d
                 m = self.moe
-                n += d * m.n_experts  # router
-                n += m.n_experts * 3 * d * m.d_expert
+                n += norm + d * m.n_experts + m.n_experts * 3 * d * m.d_expert
                 if m.n_shared:
-                    n += 3 * d * (m.d_expert * m.n_shared)
-        n += d  # final norm
+                    n += mlp(m.d_expert * m.n_shared)
+        n += norm  # final norm
         if self.encdec is not None:
             e = self.encdec
-            per_enc = d + 2 * (d * self.n_heads * hd + d) + d + 2 * d * self.d_ff
-            n += e.encoder_layers * per_enc  # rough: enc self-attn + mlp
-            n += self.n_layers * (d + 2 * d * self.n_kv_heads * hd + d * self.n_heads * hd + self.n_heads * hd * d)  # cross-attn
+            n += e.encoder_layers * (2 * norm + attn + mlp(self.d_ff, self.mlp_bias))
+            n += self.n_layers * (norm + attn)  # cross-attention and its norm
+            n += e.d_frontend * d + d + norm  # frontend projection, encoder norm
         if self.vlm is not None:
             n += self.vlm.d_vision * d + d  # mm projector
         return n
@@ -220,10 +241,12 @@ ARCH_IDS = [
     "granite_moe_1b",
     "mamba2_130m",
     "llava_next_mistral_7b",
+    "granite4_h_micro",
 ]
 
 # archs for which long_500k runs (sub-quadratic / mostly-local attention);
-# the rest skip it (pure full attention — see DESIGN.md §Arch-applicability)
+# the rest skip it: a dense KV cache of 500k positions in every attention
+# layer, or a context beyond the published one
 LONG_CTX_ARCHS = {"mamba2_130m", "jamba_v01_52b", "gemma3_1b"}
 
 

@@ -49,6 +49,7 @@ VERSION_ZSTD = 1
 VERSION_ZLIB = 2
 VERSION = VERSION_ZSTD  # kept for backwards compatibility of the constant
 DEFAULT_BLOCK_SIZE = 512 * 1024  # paper's production setting (512 KB)
+MAX_RUN_BYTES = 32 << 20  # compressed bytes a coalesced read takes at most
 
 _CODEC_BY_VERSION = {VERSION_ZSTD: "zstd", VERSION_ZLIB: "zlib"}
 _VERSION_BY_CODEC = {v: k for k, v in _CODEC_BY_VERSION.items()}
@@ -82,6 +83,18 @@ class _ZstdCodec:
     def decompress(self, data: bytes, raw_size: int) -> bytes:
         return self._d.decompress(data, max_output_size=raw_size)
 
+    def decompress_into(self, data, out: memoryview) -> None:
+        """One frame, straight into ``out`` (exactly its raw size)."""
+        reader = self._d.stream_reader(data)
+        n = 0
+        while n < len(out):
+            got = reader.readinto(out[n:])
+            if not got:
+                break
+            n += got
+        if n != len(out) or reader.read(1):
+            raise ValueError(f"block decompressed to other than {len(out)} bytes")
+
 
 class _ZlibCodec:
     name = "zlib"
@@ -97,6 +110,12 @@ class _ZlibCodec:
         if len(out) > raw_size:
             raise ValueError(f"block decompressed to {len(out)} > {raw_size} bytes")
         return out
+
+    def decompress_into(self, data, out: memoryview) -> None:
+        raw = self.decompress(data, len(out))
+        if len(raw) != len(out):
+            raise ValueError(f"block decompressed to {len(raw)}, not {len(out)} bytes")
+        out[:] = raw
 
 
 def _make_codec(name: str, level: int | None = None):
@@ -240,16 +259,21 @@ class BlockReader:
     I/O discipline: one persistent file handle for the reader's lifetime
     (use :meth:`close` or the context-manager protocol), and
     :meth:`read_range` coalesces runs of contiguous uncached blocks into a
-    single seek+read — the compressed blocks are back-to-back on disk, so a
-    cold sequential range costs one syscall instead of one per block.
-    ``stats`` accounting is unchanged: the same per-block useful/fetched
-    byte and block counts as the one-read-per-block implementation.
+    single seek+read of at most ``MAX_RUN_BYTES`` compressed bytes, into
+    one buffer the reader reuses — the compressed blocks are back-to-back
+    on disk, so a cold sequential range costs one syscall per run instead
+    of one per block. ``stats`` accounting is unchanged: the same
+    per-block useful/fetched byte and block counts as the
+    one-read-per-block implementation, each block counted once.
 
-    :meth:`read_range_into` assembles a range in place: each covering
-    block's bytes are copied once, straight into the caller's buffer (a
-    restored leaf's own host array), with no join buffer between.
-    :meth:`read_range` is the same routine into a fresh buffer, returned
-    as ``bytes``.
+    :meth:`read_range_into` assembles a range in place: a block the range
+    covers whole is decompressed straight into the caller's buffer (a
+    restored leaf's own host array) and not kept; a block it covers in
+    part is kept in the cache, since the neighbouring range needs it, and
+    its bytes are copied into place. So a restore holds no second copy of
+    the payload, and allocates no block-sized buffers that the allocator
+    may or may not hand back between restores. :meth:`read_range` is the
+    same routine into a fresh buffer, returned as ``bytes``.
     """
 
     def __init__(self, path: str, manifest: BlockManifest | None = None) -> None:
@@ -257,6 +281,8 @@ class BlockReader:
         self.manifest = manifest or read_manifest(path)
         self._data_start = _HEADER.size + 8 * (self.manifest.n_blocks + 1)
         self._cache: dict[int, bytes] = {}
+        self._fetched: set[int] = set()  # blocks counted in ``stats``
+        self._run_buf = bytearray()  # compressed runs, reused
         self._codec = _make_codec(self.manifest.codec)  # decompress side: level moot
         self.stats = ReadStats()
         self._f = open(path, "rb")
@@ -280,36 +306,53 @@ class BlockReader:
         except Exception:
             pass
 
-    def _read_at(self, data_offset: int, size: int, blocks: int = 1) -> bytes:
+    def _read_at(self, first: int, last: int) -> memoryview:
+        """Compressed blocks [first, last], read into the reused buffer."""
         if self._f is None:
             raise ValueError(f"BlockReader for {self.path} is closed")
-        with span("blockstore.read", bytes=size, blocks=blocks):
-            self._f.seek(self._data_start + data_offset)
+        m = self.manifest
+        size = m.offsets[last + 1] - m.offsets[first]
+        if len(self._run_buf) < size:
+            self._run_buf = bytearray(size)
+        view = memoryview(self._run_buf)[:size]
+        with span("blockstore.read", bytes=size, blocks=last + 1 - first):
+            self._f.seek(self._data_start + m.offsets[first])
             self.file_reads += 1
-            return self._f.read(size)
+            if self._f.readinto(view) != size:
+                raise ValueError(f"{self.path}: truncated block data")
+        return view
 
     # -- block-level -----------------------------------------------------
     def fetch_block_compressed(self, i: int) -> bytes:
         """Raw compressed block i — the unit streamed down FT edges."""
-        m = self.manifest
-        return self._read_at(m.offsets[i], m.block_compressed_size(i))
+        return bytes(self._read_at(i, i))
 
-    def _ingest(self, i: int, comp: bytes) -> bytes:
-        """Decompress + cache block ``i`` and account for the network fetch."""
-        raw = self._codec.decompress(comp, self.manifest.block_raw_size(i))
-        self._cache[i] = raw
-        self.stats.blocks_fetched += 1
-        self.stats.fetched_compressed += len(comp)
-        self.stats.fetched_raw += len(raw)
-        return raw
+    def _ingest(self, i: int, comp, into: tuple[int, memoryview] | None) -> int:
+        """Decompress block ``i`` — into its place in ``into`` = (raw
+        offset, buffer) when the buffer holds the whole block, else into
+        the cache — account for the network fetch, return its raw size."""
+        n = self.manifest.block_raw_size(i)
+        lo = i * self.manifest.block_size - into[0] if into is not None else -1
+        if 0 <= lo and lo + n <= len(into[1]):
+            self._codec.decompress_into(comp, into[1][lo : lo + n])
+        else:
+            self._cache[i] = self._codec.decompress(comp, n)
+        if i not in self._fetched:
+            self._fetched.add(i)
+            self.stats.blocks_fetched += 1
+            self.stats.fetched_compressed += len(comp)
+            self.stats.fetched_raw += n
+        return n
 
     def get_block(self, i: int) -> bytes:
-        if i in self._cache:
-            return self._cache[i]
-        return self._ingest(i, self.fetch_block_compressed(i))
+        if i not in self._cache:
+            self._fetch_run(i, i)
+        return self._cache[i]
 
-    def _fetch_run(self, first: int, last: int) -> None:
-        """Fetch uncached blocks [first, last] with one read per contiguous run."""
+    def _fetch_run(self, first: int, last: int,
+                   into: tuple[int, memoryview] | None = None) -> None:
+        """Fetch uncached blocks [first, last] with one read per contiguous
+        run of at most ``MAX_RUN_BYTES`` (see :meth:`_ingest` for ``into``)."""
         m = self.manifest
         i = first
         while i <= last:
@@ -317,13 +360,14 @@ class BlockReader:
                 i += 1
                 continue
             j = i
-            while j + 1 <= last and (j + 1) not in self._cache:
+            while (j + 1 <= last and (j + 1) not in self._cache
+                   and m.offsets[j + 2] - m.offsets[i] <= MAX_RUN_BYTES):
                 j += 1
-            run = self._read_at(m.offsets[i], m.offsets[j + 1] - m.offsets[i], j + 1 - i)
+            run = self._read_at(i, j)
             base = m.offsets[i]
             with span("blockstore.decompress", blocks=j + 1 - i) as sp:
                 sp.set(raw_bytes=sum(
-                    len(self._ingest(k, run[m.offsets[k] - base : m.offsets[k + 1] - base]))
+                    self._ingest(k, run[m.offsets[k] - base : m.offsets[k + 1] - base], into)
                     for k in range(i, j + 1)))
             i = j + 1
 
@@ -345,8 +389,9 @@ class BlockReader:
 
     def read_range_into(self, offset: int, length: int, out) -> None:
         """Raw range [offset, offset+length) into ``out``, a writable
-        C-contiguous buffer of exactly ``length`` bytes; each covering
-        block's bytes are copied once, into their place."""
+        C-contiguous buffer of exactly ``length`` bytes: blocks it covers
+        whole are decompressed into their place, the bytes of the others
+        copied there from the cache."""
         self._check_range(offset, length)
         dst = memoryview(out).cast("B")
         if dst.readonly or dst.nbytes != length:
@@ -358,13 +403,13 @@ class BlockReader:
         with span("blockstore.read_range", bytes=length, blocks=last + 1 - first,
                   blocks_cached=cached):
             if first <= last:
-                self._fetch_run(first, last)
+                self._fetch_run(first, last, (offset, dst))
             pos = 0
             for i in range(first, last + 1):
-                raw = self._cache[i]
                 lo = max(0, offset - i * m.block_size)
-                hi = min(len(raw), offset + length - i * m.block_size)
-                dst[pos : pos + hi - lo] = memoryview(raw)[lo:hi]
+                hi = min(m.block_raw_size(i), offset + length - i * m.block_size)
+                if i in self._cache:  # else decompressed into place above
+                    dst[pos : pos + hi - lo] = memoryview(self._cache[i])[lo:hi]
                 pos += hi - lo
 
     def read_all(self) -> bytes:

@@ -19,7 +19,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from .layers import apply_rope
+from .layers import apply_rope, compiled_when_eager
 
 PyTree = Any
 NEG_INF = -2.0e38
@@ -55,8 +55,17 @@ def _rms(x, scale, eps=1e-6):
     return (y * scale).astype(x.dtype)
 
 
+def softmax_scale(cfg) -> float:
+    """The scale of q·k before the softmax: the config's
+    ``attention_multiplier``, else 1/sqrt(head_dim)."""
+    if cfg.attention_multiplier is not None:
+        return cfg.attention_multiplier
+    return cfg.hd**-0.5
+
+
 def qkv_proj(p: PyTree, x: jax.Array, cfg, positions: jax.Array, inv_freq):
-    """x (B,T,d) -> q (B,H,T,hd), k/v (B,Hkv,T,hd), RoPE applied."""
+    """x (B,T,d) -> q (B,H,T,hd), k/v (B,Hkv,T,hd), RoPE applied unless
+    ``inv_freq`` is None."""
     dt = x.dtype
     q = jnp.einsum("btd,dhk->bhtk", x, p["wq"].astype(dt))
     k = jnp.einsum("btd,dhk->bhtk", x, p["wk"].astype(dt))
@@ -66,8 +75,8 @@ def qkv_proj(p: PyTree, x: jax.Array, cfg, positions: jax.Array, inv_freq):
         k = k + p["bk"].astype(dt)[None, :, None, :]
         v = v + p["bv"].astype(dt)[None, :, None, :]
     if "q_norm" in p:
-        q = _rms(q, p["q_norm"]["scale"])
-        k = _rms(k, p["k_norm"]["scale"])
+        q = _rms(q, p["q_norm"]["scale"], cfg.norm_eps)
+        k = _rms(k, p["k_norm"]["scale"], cfg.norm_eps)
     if inv_freq is not None:
         pos = positions[:, None, :]  # (B,1,T) broadcasting over heads
         q = apply_rope(q, pos, inv_freq)
@@ -279,12 +288,16 @@ def attend_decode_plus_new_gqa(
     return o.reshape(b, h, 1, hd)
 
 
+_attend_chunked = compiled_when_eager(attend_chunked, ("window", "scale", "chunk"))
+
+
 def attention(
     q, k, v, *, impl: str, q_pos, k_pos, window, scale, chunk: int = 1024
 ):
     """Dispatch on implementation for prefill/train (q_len == kv_len)."""
     if impl == "chunked":
-        return attend_chunked(q, k, v, q_pos, k_pos, window, scale, chunk=chunk)
+        return _attend_chunked(q, k, v, q_pos, k_pos, window=window, scale=scale,
+                               chunk=chunk)
     if impl == "pallas":
         from repro.kernels import ops as kops
 

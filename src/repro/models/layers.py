@@ -20,6 +20,24 @@ import numpy as np
 PyTree = Any
 
 
+def compiled_when_eager(fn, static_argnames: tuple[str, ...]):
+    """``fn`` as one compiled program where no argument is a tracer (a call
+    outside any jit, scan or grad), else ``fn`` itself, traced inline.
+
+    For functions built on ``lax.scan`` with a body defined inside them:
+    called op by op, such a body is a new closure at every call, so each
+    call traces and compiles the scan again; compiled whole, it is cached
+    by shape. Inside a trace nothing changes."""
+    compiled = jax.jit(fn, static_argnames=static_argnames)
+
+    def call(*args, **kwargs):
+        if any(isinstance(a, jax.core.Tracer) for a in jax.tree.leaves((args, kwargs))):
+            return fn(*args, **kwargs)
+        return compiled(*args, **kwargs)
+
+    return call
+
+
 def _normal(key, shape, scale):
     return (scale * jax.random.normal(key, shape, dtype=jnp.float32)).astype(
         jnp.float32

@@ -17,7 +17,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from .layers import _normal, apply_norm, init_norm
+from .layers import _normal, apply_norm, compiled_when_eager, init_norm
 
 PyTree = Any
 
@@ -42,24 +42,31 @@ def init_mamba(key, cfg) -> PyTree:
         "out_norm": init_norm("rmsnorm", h * p),
         "w_out": _normal(keys[4], (h * p, d), (h * p) ** -0.5),
     }
+    if cfg.ssm_conv_bias:
+        for c, ch in (("x", h * p), ("B", g * n), ("C", g * n)):
+            params[f"conv_{c}_bias"] = jnp.zeros((ch,), jnp.float32)
     return params
 
 
-def causal_conv(x: jax.Array, kernel: jax.Array) -> jax.Array:
-    """Depthwise causal conv: x (B,T,Ch), kernel (W,Ch)."""
+def causal_conv(x: jax.Array, kernel: jax.Array, bias: jax.Array | None = None) -> jax.Array:
+    """Depthwise causal conv: x (B,T,Ch), kernel (W,Ch), bias (Ch,) or None."""
     w, ch = kernel.shape
     pad = jnp.pad(x, ((0, 0), (w - 1, 0), (0, 0)))
     out = jnp.zeros_like(x)
     for i in range(w):  # W is 4: unrolled taps beat a conv op for HLO size
         out = out + pad[:, i : i + x.shape[1], :] * kernel[i].astype(x.dtype)
+    if bias is not None:
+        out = out + bias.astype(x.dtype)
     return out
 
 
-def conv_step(x_new: jax.Array, conv_state: jax.Array, kernel: jax.Array):
+def conv_step(x_new: jax.Array, conv_state: jax.Array, kernel: jax.Array,
+              bias: jax.Array | None = None):
     """One decode step. x_new (B,Ch); conv_state (B,W-1,Ch) holds history."""
-    w = kernel.shape[0]
     window = jnp.concatenate([conv_state, x_new[:, None, :]], axis=1)  # (B,W,Ch)
     y = jnp.einsum("bwc,wc->bc", window.astype(x_new.dtype), kernel.astype(x_new.dtype))
+    if bias is not None:
+        y = y + bias.astype(x_new.dtype)
     return y, window[:, 1:, :]
 
 
@@ -163,6 +170,9 @@ def ssd_chunked(
     return y.astype(x.dtype), final
 
 
+ssd = compiled_when_eager(ssd_chunked, ("chunk", "intra_dtype"))
+
+
 def ssd_step(
     x: jax.Array,  # (B,H,P)
     dt: jax.Array,  # (B,H)
@@ -205,14 +215,14 @@ def apply_mamba(
     Bp = x @ p["w_B"].astype(dt_)  # (B,T,G*N)
     Cp = x @ p["w_C"].astype(dt_)
     dt_raw = x @ p["w_dt"].astype(dt_)  # (B,T,H)
-    A = -jnp.exp(p["A_log"])  # (H,)
+    A = -jnp.exp(p["A_log"].astype(jnp.float32))  # (H,)
 
     if cache is None:
-        xs = jax.nn.silu(causal_conv(xs, p["conv_x"]))
-        Bp = jax.nn.silu(causal_conv(Bp, p["conv_B"]))
-        Cp = jax.nn.silu(causal_conv(Cp, p["conv_C"]))
+        xs = jax.nn.silu(causal_conv(xs, p["conv_x"], p.get("conv_x_bias")))
+        Bp = jax.nn.silu(causal_conv(Bp, p["conv_B"], p.get("conv_B_bias")))
+        Cp = jax.nn.silu(causal_conv(Cp, p["conv_C"], p.get("conv_C_bias")))
         dt_v = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])
-        y, final = ssd_chunked(
+        y, final = ssd(
             xs.reshape(b, t, h, pd),
             dt_v,
             A,
@@ -224,9 +234,9 @@ def apply_mamba(
         new_cache = None
     else:
         assert t == 1, "decode path expects a single new token"
-        xs1, conv_x = conv_step(xs[:, 0], cache["conv_x"], p["conv_x"])
-        Bp1, conv_B = conv_step(Bp[:, 0], cache["conv_B"], p["conv_B"])
-        Cp1, conv_C = conv_step(Cp[:, 0], cache["conv_C"], p["conv_C"])
+        xs1, conv_x = conv_step(xs[:, 0], cache["conv_x"], p["conv_x"], p.get("conv_x_bias"))
+        Bp1, conv_B = conv_step(Bp[:, 0], cache["conv_B"], p["conv_B"], p.get("conv_B_bias"))
+        Cp1, conv_C = conv_step(Cp[:, 0], cache["conv_C"], p["conv_C"], p.get("conv_C_bias"))
         xs1, Bp1, Cp1 = jax.nn.silu(xs1), jax.nn.silu(Bp1), jax.nn.silu(Cp1)
         dt_v = jax.nn.softplus(dt_raw[:, 0].astype(jnp.float32) + p["dt_bias"])
         y1, ssm = ssd_step(
@@ -245,7 +255,7 @@ def apply_mamba(
         dt_
     ).repeat(pd)
     yd = yd * jax.nn.silu(z)
-    yd = apply_norm("rmsnorm", p["out_norm"], yd)
+    yd = apply_norm("rmsnorm", p["out_norm"], yd, cfg.norm_eps)  # over all H*P channels
     return yd @ p["w_out"].astype(dt_), new_cache
 
 

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.api import constrain
+from repro.tracing import layer_span
 
 from . import attention as attn
 from .layers import (
@@ -125,6 +126,9 @@ def init_params(key, cfg) -> PyTree:
     if cfg.vlm is not None:
         params["mm_proj"] = init_linear(keys[3], cfg.vlm.d_vision, cfg.d_model,
                                         bias=True)
+    if cfg.param_dtype != "float32":
+        dtype = jnp.dtype(cfg.param_dtype)
+        params = jax.tree.map(lambda a: a.astype(dtype), params)
     return params
 
 
@@ -187,7 +191,7 @@ def _apply_attn(cfg, spec, p, x, *, positions, inv_freq, cache, pos, mode,
                 cache_len=None):
     h = cfg.n_heads
     rep = h // cfg.n_kv_heads
-    scale = cfg.hd**-0.5
+    scale = attn.softmax_scale(cfg)
     q, k, v = attn.qkv_proj(p, x, cfg, positions, inv_freq)
     window = None if spec.is_global else cfg.sliding_window
     if mode in ("train", "prefill"):
@@ -254,40 +258,48 @@ def _apply_attn(cfg, spec, p, x, *, positions, inv_freq, cache, pos, mode,
     return attn.out_proj(p, o), new_cache
 
 
+def _residual(cfg, x, h):
+    if cfg.residual_multiplier != 1:
+        h = h * jnp.asarray(cfg.residual_multiplier, h.dtype)
+    return constrain(x + h, ("data", None, None))
+
+
 def _apply_layer(cfg, spec, p, x, *, positions, inv_freq, cache, pos, mode,
                  cache_len=None):
     aux = jnp.zeros((), jnp.float32)
-    h_in = apply_norm(cfg.norm, p["norm1"], x)
+    tokens = x.shape[0] * x.shape[1]
+    h_in = apply_norm(cfg.norm, p["norm1"], x, cfg.norm_eps)
     if spec.mixer == "attn":
-        h, new_cache = _apply_attn(
-            cfg, spec, p["attn"], h_in,
-            positions=positions, inv_freq=inv_freq, cache=cache, pos=pos,
-            mode=mode, cache_len=cache_len,
-        )
+        with layer_span("model.attn", tokens=tokens):
+            h, new_cache = _apply_attn(
+                cfg, spec, p["attn"], h_in,
+                positions=positions, inv_freq=inv_freq, cache=cache, pos=pos,
+                mode=mode, cache_len=cache_len,
+            )
     else:
-        h, new_cache = apply_mamba(
-            p["mamba"], h_in, cfg,
-            cache=cache if mode == "decode" else None, chunk=cfg.ssm.chunk,
-        )
-        if mode == "prefill":
-            new_cache = _mamba_prefill_cache(p["mamba"], h_in, cfg)
-    x = x + h
-    x = constrain(x, ("data", None, None))
+        with layer_span("model.mamba", tokens=tokens):
+            h, new_cache = apply_mamba(
+                p["mamba"], h_in, cfg,
+                cache=cache if mode == "decode" else None, chunk=cfg.ssm.chunk,
+            )
+            if mode == "prefill":
+                new_cache = _mamba_prefill_cache(p["mamba"], h_in, cfg)
+    x = _residual(cfg, x, h)
     if spec.ffn != "none":
-        h2 = apply_norm(cfg.norm, p["norm2"], x)
+        h2 = apply_norm(cfg.norm, p["norm2"], x, cfg.norm_eps)
         if spec.ffn == "moe":
             h2, a = apply_moe(p["moe"], h2, cfg)
             aux = aux + a
         else:
-            h2 = apply_mlp(p["mlp"], h2, cfg.act)
-        x = x + h2
-        x = constrain(x, ("data", None, None))
+            with layer_span("model.mlp", tokens=tokens):
+                h2 = apply_mlp(p["mlp"], h2, cfg.act)
+        x = _residual(cfg, x, h2)
     return x, new_cache, aux
 
 
 def _mamba_prefill_cache(p, x_normed_in, cfg):
     """Build decode cache from a prefill pass (conv tail + final SSD state)."""
-    from .mamba2 import ssd_chunked
+    from .mamba2 import ssd
 
     s = cfg.ssm
     h, pd, g, n = s.n_heads, s.head_dim, s.n_groups, s.d_state
@@ -296,14 +308,16 @@ def _mamba_prefill_cache(p, x_normed_in, cfg):
     # recompute the projections (cheap relative to carrying them through)
     from .mamba2 import causal_conv
 
-    xs = jax.nn.silu(causal_conv(x_normed_in @ p["w_x"].astype(dt_), p["conv_x"]))
-    Bp = jax.nn.silu(causal_conv(x_normed_in @ p["w_B"].astype(dt_), p["conv_B"]))
-    Cp = jax.nn.silu(causal_conv(x_normed_in @ p["w_C"].astype(dt_), p["conv_C"]))
+    xs, Bp, Cp = (
+        jax.nn.silu(causal_conv(x_normed_in @ p[f"w_{c}"].astype(dt_), p[f"conv_{c}"],
+                                p.get(f"conv_{c}_bias")))
+        for c in ("x", "B", "C")
+    )
     dt_v = jax.nn.softplus(
         (x_normed_in @ p["w_dt"].astype(dt_)).astype(jnp.float32) + p["dt_bias"]
     )
-    A = -jnp.exp(p["A_log"])
-    _, final = ssd_chunked(
+    A = -jnp.exp(p["A_log"].astype(jnp.float32))
+    _, final = ssd(
         xs.reshape(b, t, h, pd), dt_v, A,
         Bp.reshape(b, t, g, n), Cp.reshape(b, t, g, n), chunk=s.chunk,
     )
@@ -389,6 +403,8 @@ def embed_inputs(params, cfg, batch: dict, mode: str) -> jax.Array:
     x = embed(params["embed"], batch["tokens"], dtype)
     if cfg.embed_scale:
         x = x * jnp.asarray(cfg.d_model**0.5, dtype)
+    if cfg.embedding_multiplier != 1:
+        x = x * jnp.asarray(cfg.embedding_multiplier, dtype)
     if cfg.vlm is not None and "patch_embeds" in batch:
         vis = apply_linear(params["mm_proj"], batch["patch_embeds"].astype(dtype))
         x = jnp.concatenate([vis, x], axis=1)
@@ -415,11 +431,9 @@ def forward(
     else:
         pos = None
         positions = jnp.broadcast_to(jnp.arange(t)[None], (b, t))
-    inv_freq = (
-        jnp.asarray(rope_freqs(cfg.hd, cfg.rope_theta, cfg.rope_pct))
-        if cfg.attn_every
-        else None
-    )
+    # None where nothing rotates: attention-free, or no position embedding
+    inv_freq = rope_freqs(cfg.hd, cfg.rope_theta, cfg.rope_pct) if cfg.attn_every else None
+    inv_freq = jnp.asarray(inv_freq) if inv_freq is not None and inv_freq.size else None
     aux = jnp.zeros((), jnp.float32)
     new_caches = []
     for i, st in enumerate(stages):
@@ -431,10 +445,12 @@ def forward(
         )
         new_caches.append(nc)
         aux = aux + a
-    x = apply_norm(cfg.norm, params["final_norm"], x)
+    x = apply_norm(cfg.norm, params["final_norm"], x, cfg.norm_eps)
     if cfg.tie_embeddings:
         logits = unembed(params["embed"], x)
     else:
         logits = apply_linear(params["lm_head"], x)
+    if cfg.logits_scaling != 1:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     logits = constrain(logits, ("data", None, "model"))
     return logits, (new_caches if mode in ("prefill", "decode") else None), aux

@@ -94,28 +94,28 @@ def encode(params, cfg, frames: jax.Array) -> jax.Array:
     x = apply_linear(params["frontend_proj"], frames.astype(dtype))
     x = x + sinusoid(jnp.arange(x.shape[1]), cfg.d_model, dtype)[None]
     x = constrain(x, ("data", None, None))
-    scale = cfg.hd**-0.5
+    scale = attn.softmax_scale(cfg)
     rep = cfg.n_heads // cfg.n_kv_heads
 
     def body(x, p):
-        h = apply_norm(cfg.norm, p["norm1"], x)
+        h = apply_norm(cfg.norm, p["norm1"], x, cfg.norm_eps)
         q, k, v = attn.qkv_proj(p["attn"], h, cfg, None, None)
         o = attn.attend_full(q, attn.repeat_kv(k, rep), attn.repeat_kv(v, rep),
                              None, scale)
         x = x + attn.out_proj(p["attn"], o)
-        h = apply_norm(cfg.norm, p["norm2"], x)
+        h = apply_norm(cfg.norm, p["norm2"], x, cfg.norm_eps)
         x = x + apply_mlp(p["mlp"], h, cfg.act)
         return constrain(x, ("data", None, None)), None
 
     x, _ = jax.lax.scan(body, x, params["enc_layers"])
-    return apply_norm(cfg.norm, params["enc_norm"], x)
+    return apply_norm(cfg.norm, params["enc_norm"], x, cfg.norm_eps)
 
 
 def _dec_layer(cfg, p, x, enc_kv, *, positions, self_cache, pos, mode):
-    scale = cfg.hd**-0.5
+    scale = attn.softmax_scale(cfg)
     rep = cfg.n_heads // cfg.n_kv_heads
     # self-attention (causal)
-    h = apply_norm(cfg.norm, p["norm1"], x)
+    h = apply_norm(cfg.norm, p["norm1"], x, cfg.norm_eps)
     q, k, v = attn.qkv_proj(p["self_attn"], h, cfg, None, None)
     # sinusoidal positions are added at the embedding; no RoPE here
     if mode == "decode":
@@ -140,14 +140,14 @@ def _dec_layer(cfg, p, x, enc_kv, *, positions, self_cache, pos, mode):
         new_cache = {"k": k, "v": v} if mode == "prefill" else None
     x = x + attn.out_proj(p["self_attn"], o)
     # cross-attention over encoder output (precomputed per-layer K/V)
-    h = apply_norm(cfg.norm, p["norm_x"], x)
+    h = apply_norm(cfg.norm, p["norm_x"], x, cfg.norm_eps)
     qx = jnp.einsum("btd,dhk->bhtk", h, p["cross_attn"]["wq"].astype(h.dtype))
     kx, vx = enc_kv
     ox = attn.attend_full(qx, attn.repeat_kv(kx, rep), attn.repeat_kv(vx, rep),
                           None, scale)
     x = x + attn.out_proj(p["cross_attn"], ox)
     # mlp
-    h = apply_norm(cfg.norm, p["norm2"], x)
+    h = apply_norm(cfg.norm, p["norm2"], x, cfg.norm_eps)
     x = x + apply_mlp(p["mlp"], h, cfg.act)
     return constrain(x, ("data", None, None)), new_cache
 
@@ -222,7 +222,7 @@ def forward(
         if cfg.remat == "block" and mode == "train":
             body = jax.checkpoint(body)
         x, new_self = jax.lax.scan(body, x, (params["dec_layers"], enc_kv_all))
-    x = apply_norm(cfg.norm, params["final_norm"], x)
+    x = apply_norm(cfg.norm, params["final_norm"], x, cfg.norm_eps)
     logits = x @ params["embed"]["table"].astype(dtype).T  # whisper ties embeddings
     logits = constrain(logits, ("data", None, "model"))
     aux = jnp.zeros((), jnp.float32)

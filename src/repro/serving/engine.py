@@ -135,6 +135,7 @@ class ServeEngine:
                 logits, cache = self.model.prefill(
                     self.params, {"tokens": jnp.asarray(toks)}, cache_len=cache_len
                 )
+                prefill.set(**cache_bytes(cache))
             with span("serve.first_token"):
                 last = jnp.argmax(logits[:, -1], axis=-1)
                 for i, r in enumerate(batch_reqs):
@@ -163,6 +164,21 @@ class ServeEngine:
         return batch_reqs
 
 
+_SSM_LEAVES = {"conv_x", "conv_B", "conv_C", "ssm"}  # Mamba-2: conv tails, state
+
+
+def cache_bytes(cache: PyTree) -> dict:
+    """Bytes of a decode cache by kind, from the leaves' shapes and dtypes
+    (nothing waits for the device): ``ssm_bytes``, the Mamba-2 layers' conv
+    tails and states, and ``kv_bytes``, every other leaf: the attention
+    layers' keys and values (and their int8 scales)."""
+    out = {"kv_bytes": 0, "ssm_bytes": 0}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
+        kind = "ssm_bytes" if getattr(path[-1], "key", None) in _SSM_LEAVES else "kv_bytes"
+        out[kind] += leaf.size * leaf.dtype.itemsize
+    return out
+
+
 def _restore_stats(totals: dict) -> dict:
     """A restore's counters from its root span's ``totals``
     (``repro.tracing``)."""
@@ -175,7 +191,7 @@ def _restore_stats(totals: dict) -> dict:
         "t_full_s": t("restore.pass", "seconds"),
         "read_s": read,
         "decompress_s": decompress,
-        # the block joins in read_range, then the leaf decode
+        # copies of cached blocks in read_range, then the leaf decode
         "decode_s": (t("blockstore.read_range", "seconds") - read - decompress
                      + t("restore.decode", "seconds")),
         "h2d_s": t("restore.h2d", "seconds"),

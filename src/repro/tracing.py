@@ -14,7 +14,12 @@ counters from there and measures each interval once.
 JAX's compile events are counted on the innermost open span of the
 thread that raised them: ``traces`` (jaxprs traced), ``cache_loads``
 (executables loaded from the persistent cache) and ``compiles`` (backend
-compile requests less those loads).
+compile requests less those loads). ``layer_span`` opens a span around
+one layer of a model: timed, annotated and summed like any other, but it
+takes no compile counts, which go on to the span around it, so that a
+caller's counts (``serve.prefill``'s) do not depend on how finely the
+model is spanned. Inside ``jit`` or a ``lax.scan`` body a layer's span
+covers its tracing and records once per trace, not once per run.
 
 JAX is imported at the first span, not with this module, and where it
 cannot be imported a span is timed and counted all the same, with no
@@ -43,10 +48,10 @@ def _stack() -> list:
 
 
 def _count(key: str, n: int = 1) -> None:
-    stack = getattr(_local, "stack", None)
-    if stack:
-        counts = stack[-1].counts
-        counts[key] = counts.get(key, 0) + n
+    for sp in reversed(getattr(_local, "stack", None) or ()):
+        if sp.takes_counts:
+            sp.counts[key] = sp.counts.get(key, 0) + n
+            return
 
 
 def _on_duration(event: str, _secs: float, **_kw) -> None:
@@ -95,11 +100,13 @@ def _listen() -> None:
 class Span:
     """One span of the program; see the module's docstring."""
 
-    __slots__ = ("name", "args", "counts", "totals", "seconds", "_tm", "_t0")
+    __slots__ = ("name", "args", "takes_counts", "counts", "totals", "seconds",
+                 "_tm", "_t0")
 
-    def __init__(self, name: str, args: dict) -> None:
+    def __init__(self, name: str, args: dict, takes_counts: bool = True) -> None:
         self.name = name
         self.args = args
+        self.takes_counts = takes_counts
         self.counts: dict = {}
         self.totals: dict[tuple[str, str], float] = {}
         self.seconds: float | None = None
@@ -138,6 +145,12 @@ class Span:
 def span(name: str, **args) -> Span:
     """A span named ``name`` carrying ``args``, to be used in ``with``."""
     return Span(name, args)
+
+
+def layer_span(name: str, **args) -> Span:
+    """A span around one layer of a model; compile events inside it count
+    on the enclosing span (see the module's docstring)."""
+    return Span(name, args, takes_counts=False)
 
 
 def total(totals: dict, key: str) -> float:

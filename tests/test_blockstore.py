@@ -397,3 +397,41 @@ def test_read_range_into_rejects_a_wrong_buffer(into_path):
         with pytest.raises(ValueError):
             r.read_range_into(0, 100, out)
     assert r.stats == ReadStats() and r.file_reads == 0  # nothing fetched
+
+
+def test_read_range_into_places_whole_blocks_and_keeps_the_edges(into_path):
+    """Blocks the range covers whole go straight into the buffer and are not
+    kept; the two it covers in part stay cached for the neighbouring range.
+    Reading the range again counts no block twice."""
+    r = BlockReader(into_path)
+    off, ln = INTO_RANGES["across_block_edges"]  # blocks 0 and 3 in part, 1-2 whole
+    out = bytearray(ln)
+    r.read_range_into(off, ln, out)
+    assert bytes(out) == INTO_PAYLOAD[off : off + ln]
+    assert sorted(r._cache) == [0, 3]
+    stats = (r.stats.blocks_fetched, r.stats.fetched_compressed, r.stats.fetched_raw)
+    assert stats[0] == 4
+    assert r.read_range(off, ln) == INTO_PAYLOAD[off : off + ln]
+    assert (r.stats.blocks_fetched, r.stats.fetched_compressed, r.stats.fetched_raw) == stats
+
+
+def test_coalesced_reads_stop_at_max_run_bytes(into_path, monkeypatch):
+    from repro.core import blockstore as bs
+
+    r = BlockReader(into_path)
+    m = r.manifest
+    monkeypatch.setattr(bs, "MAX_RUN_BYTES", max(
+        m.block_compressed_size(i) for i in range(m.n_blocks)))
+    assert r.read_range(0, m.raw_size) == INTO_PAYLOAD
+    assert r.file_reads == m.n_blocks  # a cap of one block reads block by block
+    assert r.stats.blocks_fetched == m.n_blocks
+
+
+def test_read_range_into_with_the_zlib_codec(tmp_path):
+    path = str(tmp_path / "z.blocks")
+    write_blockstore(INTO_PAYLOAD, path, block_size=BS, codec="zlib")
+    r = BlockReader(path)
+    for off, ln in INTO_RANGES.values():
+        out = np.empty(ln, np.uint8)
+        r.read_range_into(off, ln, out)
+        assert out.tobytes() == INTO_PAYLOAD[off : off + ln], (off, ln)

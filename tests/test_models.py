@@ -161,3 +161,102 @@ def test_window_tile_skip_matches_full():
         out = attend_chunked(q, k, v, pos, pos, window, hd**-0.5, chunk=chunk)
         exp = attend_full(q, k, v, causal_window_mask(pos, pos, window), hd**-0.5)
         assert float(jnp.max(jnp.abs(out - exp))) < 1e-5, (t, chunk, window)
+
+
+# The tree of the benchmarked Mamba-2 130M as it was before the Granite
+# options (conv bias, param dtype, norm eps, multipliers) came in: they
+# default off, so its checkpoint and numerics stay as they were.
+MAMBA2_130M_TREE = {
+    "embed/table": (50280, 768),
+    "final_norm/scale": (768,),
+    "stages/0/0/mamba/A_log": (24, 24),
+    "stages/0/0/mamba/D": (24, 24),
+    "stages/0/0/mamba/conv_B": (24, 4, 128),
+    "stages/0/0/mamba/conv_C": (24, 4, 128),
+    "stages/0/0/mamba/conv_x": (24, 4, 1536),
+    "stages/0/0/mamba/dt_bias": (24, 24),
+    "stages/0/0/mamba/out_norm/scale": (24, 1536),
+    "stages/0/0/mamba/w_B": (24, 768, 128),
+    "stages/0/0/mamba/w_C": (24, 768, 128),
+    "stages/0/0/mamba/w_dt": (24, 768, 24),
+    "stages/0/0/mamba/w_out": (24, 1536, 768),
+    "stages/0/0/mamba/w_x": (24, 768, 1536),
+    "stages/0/0/mamba/w_z": (24, 768, 1536),
+    "stages/0/0/norm1/scale": (24, 768),
+}
+
+
+def _shapes(cfg) -> dict:
+    tree = jax.eval_shape(model_for(cfg).init, jax.random.key(0))
+    return {"/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path): leaf
+            for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+def test_mamba2_130m_tree_is_unchanged_by_the_new_options():
+    from repro.configs import mamba2_130m
+
+    leaves = _shapes(mamba2_130m.CONFIG)
+    assert {k: tuple(v.shape) for k, v in leaves.items()} == MAMBA2_130M_TREE
+    assert {v.dtype for v in leaves.values()} == {jnp.dtype(jnp.float32)}
+    assert sum(int(np.prod(v.shape)) for v in leaves.values()) == 128_940_480
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_param_count_is_the_tree_size(arch):
+    """``param_count`` counts every leaf ``init`` makes, at the published
+    size (shapes only) and at the smoke size."""
+    from repro.configs import get
+
+    for cfg in (get(arch).CONFIG, get_smoke(arch)):
+        n = sum(int(np.prod(v.shape)) for v in _shapes(cfg).values())
+        assert cfg.param_count() == n, (cfg.name, cfg.param_count(), n)
+
+
+def test_granite4_h_micro_tree():
+    """Published Granite 4.0-H Micro: 3,191,396,096 parameters in bfloat16,
+    conv biases on every Mamba-2 layer; the benchmark's one-period cut
+    holds 951,991,232 of them, in one unstacked stage of ten layers."""
+    from repro.configs import granite4_h_micro as g
+
+    assert g.CONFIG.param_count() == 3_191_396_096
+    leaves = _shapes(g.ONE_PERIOD)
+    assert sum(int(np.prod(v.shape)) for v in leaves.values()) == 951_991_232
+    assert {v.dtype for v in leaves.values()} == {jnp.dtype(jnp.bfloat16)}
+    assert leaves["stages/0/5/attn/wq"].shape == (2048, 32, 64)
+    assert leaves["stages/0/0/mamba/conv_x_bias"].shape == (4096,)
+    assert sum(k.endswith("_bias") and "conv_" in k for k in leaves) == 27
+    assert [i for i in range(10) if f"stages/0/{i}/attn/wq" in leaves] == [5]
+
+
+def test_eager_prefill_of_unstacked_layers_compiles_once():
+    """Granite's one period is an unstacked stage, run op by op: its SSD
+    and attention scans are compiled whole and cached, so a second prefill
+    of the same shape traces and compiles nothing; the compiled scans give
+    the bits the op-by-op ones gave."""
+    from repro import tracing
+    from repro.configs import granite4_h_micro
+    from repro.models.attention import _attend_chunked, attend_chunked
+    from repro.models.mamba2 import ssd, ssd_chunked
+
+    cfg = granite4_h_micro.SMOKE
+    m = model_for(cfg)
+    params = m.init(jax.random.key(0))
+    batch = make_batch(cfg, 40, 1, kind="prefill", seed=2)
+    m.prefill(params, batch, cache_len=41)
+    with tracing.span("prefill") as sp:
+        m.prefill(params, batch, cache_len=41)
+    assert sp.counts.get("traces", 0) == 0 and sp.counts.get("compiles", 0) == 0
+
+    k = jax.random.split(jax.random.key(1), 5)
+    x = jax.random.normal(k[0], (2, 40, 4, 8), jnp.bfloat16)
+    dt = jax.nn.softplus(jax.random.normal(k[1], (2, 40, 4)))
+    bm, cm = (jax.random.normal(kk, (2, 40, 1, 16), jnp.bfloat16) for kk in k[2:4])
+    a = -jnp.arange(1.0, 5.0)
+    for got, want in zip(ssd(x, dt, a, bm, cm, chunk=16), ssd_chunked(x, dt, a, bm, cm, chunk=16)):
+        np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    q = jax.random.normal(k[4], (2, 4, 40, 8), jnp.bfloat16)
+    pos = jnp.arange(40)
+    np.testing.assert_array_equal(
+        np.asarray(_attend_chunked(q, q, q, pos, pos, window=None, scale=0.125, chunk=16),
+                   np.float32),
+        np.asarray(attend_chunked(q, q, q, pos, pos, None, 0.125, chunk=16), np.float32))

@@ -177,6 +177,16 @@ def test_span_totals_and_compile_counts():
     assert outer.seconds >= outer.totals["inner", "seconds"] > 0
 
 
+def test_layer_span_leaves_compile_counts_to_its_parent():
+    import jax.numpy as jnp
+
+    with tracing.span("outer") as outer:
+        with tracing.layer_span("model.x", tokens=5) as layer:
+            jax.jit(lambda x: x * 3 - 2)(jnp.arange(6))  # traced here, first use
+    assert outer.counts["traces"] >= 1 and "traces" not in layer.counts
+    assert outer.totals["model.x", "tokens"] == 5 and layer.seconds > 0
+
+
 def test_block_reads_are_timed_without_jax(tmp_path):
     """The block store is host-only: with JAX unimportable its spans still
     time and count, with no profiler annotation."""
@@ -219,3 +229,45 @@ def test_launcher_prints_the_restore_split(tmp_path, monkeypatch, capsys):
     for word in ("read", "decompress", "decode", "host-to-device", "traces",
                  "persistent cache"):
         assert word in out, word
+
+
+def test_layer_spans_of_one_hybrid_prefill(tmp_path):
+    """One eager prefill of the Granite 4.0-H smoke model (one period: nine
+    Mamba-2 layers, attention at index 5, an MLP in each) records a span
+    per sublayer inside ``serve.prefill``, each with its tokens; the layer
+    spans take no compile counts, which stay on ``serve.prefill``; and
+    ``serve.prefill`` carries the bytes of the cache it returned by kind."""
+    from repro.configs import granite4_h_micro
+
+    cfg = granite4_h_micro.SMOKE
+    eng = ServeEngine(cfg, max_batch=1)
+    eng.set_params(model_for(cfg).init(jax.random.key(0)))
+    t, new = 24, 1
+    eng.submit(np.arange(1, t + 1, dtype=np.int32), new)
+    d = str(tmp_path / "trace")
+    jax.profiler.start_trace(d)
+    try:
+        eng.step_batch()
+    finally:
+        jax.profiler.stop_trace()
+    from jax.profiler import ProfileData
+
+    path = glob.glob(f"{d}/**/*.xplane.pb", recursive=True)[0]
+    spans = [(e.start_ns, e.start_ns + e.duration_ns, e.name, dict(e.stats))
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:") for line in plane.lines
+             for e in line.events if e.name.startswith(("model.", "serve.prefill"))]
+    names = [s[2] for s in spans]
+    assert {n: names.count(n) for n in set(names)} == {
+        "serve.prefill": 1, "model.attn": 1, "model.mamba": 9, "model.mlp": 10}
+    (prefill,) = [s for s in spans if s[2] == "serve.prefill"]
+    for s in spans:
+        if s is not prefill:
+            assert prefill[0] <= s[0] and s[1] <= prefill[1], s[2]
+            assert s[3]["tokens"] == t and "traces" not in s[3], s
+    assert prefill[3]["traces"] >= 1
+    cache_len, ssm = t + new, cfg.ssm
+    assert prefill[3]["kv_bytes"] == 2 * cfg.n_kv_heads * cache_len * cfg.hd * 2
+    conv = (ssm.conv_width - 1) * (ssm.n_heads * ssm.head_dim + 2 * ssm.d_state) * 2
+    state = ssm.n_heads * ssm.head_dim * ssm.d_state * 4
+    assert prefill[3]["ssm_bytes"] == 9 * (conv + state)
