@@ -31,9 +31,13 @@ The format is used by three layers:
 """
 from __future__ import annotations
 
+import math
 import os
+import queue
 import struct
+import threading
 import zlib
+from concurrent.futures import Future
 from dataclasses import dataclass, field
 
 from repro.tracing import span
@@ -55,6 +59,7 @@ _CODEC_BY_VERSION = {VERSION_ZSTD: "zstd", VERSION_ZLIB: "zlib"}
 _VERSION_BY_CODEC = {v: k for k, v in _CODEC_BY_VERSION.items()}
 
 _HEADER = struct.Struct("<IIQQQ")
+_POOL_MAX = 8  # a run's whole blocks go to at most this many threads
 
 
 def have_zstd() -> bool:
@@ -125,6 +130,115 @@ def _make_codec(name: str, level: int | None = None):
     if name == "zlib":
         return _ZlibCodec() if level is None else _ZlibCodec(level)
     raise ValueError(f"unknown blockstore codec {name!r}")
+
+
+_CODEC_ERRORS = (zlib.error,) + ((_zstd.ZstdError,) if _zstd is not None else ())
+_per_thread = threading.local()  # each thread's decompression contexts, by codec
+
+
+def _thread_codec(name: str):
+    """This thread's own codec ``name``: a ``ZstdDecompressor`` is not safe
+    to share between threads."""
+    codec = getattr(_per_thread, name, None)
+    if codec is None:
+        codec = _make_codec(name)
+        setattr(_per_thread, name, codec)
+    return codec
+
+
+def _decompress_blocks(codec_name: str, path: str, items: list) -> None:
+    """Each ``(block, compressed, out)`` of ``items`` into its ``out``; a
+    corrupt block or one of another size raises ``ValueError``."""
+    codec = _thread_codec(codec_name)
+    for i, comp, out in items:
+        try:
+            codec.decompress_into(comp, out)
+        except (ValueError, *_CODEC_ERRORS) as e:
+            raise ValueError(f"{path}: block {i}: {e}") from e
+
+
+def _words(path: str) -> list[str]:
+    try:
+        with open(path) as f:
+            return f.read().split()
+    except OSError:
+        return []
+
+
+def _cgroup_cpus() -> float:
+    """CPUs the quota of the process's cgroup allows (v2 ``cpu.max``, v1
+    ``cpu.cfs_quota_us`` over ``cpu.cfs_period_us``; at the cgroup's own
+    path and at the mount's root), ``inf`` where none is set."""
+    cpus = math.inf
+    dirs = {""} | {e.split(":", 2)[2].rstrip("/") for e in _words("/proc/self/cgroup")}
+    for d in dirs:
+        v1 = f"/sys/fs/cgroup/cpu{d}/cpu.cfs_"
+        for quota in (_words(f"/sys/fs/cgroup{d}/cpu.max"),
+                      _words(v1 + "quota_us") + _words(v1 + "period_us")):
+            if len(quota) == 2 and quota[0] not in ("max", "-1"):
+                cpus = min(cpus, int(quota[0]) / int(quota[1]))
+    return cpus
+
+
+def _pool_width() -> int:
+    """Threads a run's whole blocks are split over, the calling thread's
+    included: the CPUs this process may run on, by affinity and by a
+    cgroup quota where that is lower, at most ``_POOL_MAX``."""
+    cpus = len(os.sched_getaffinity(0)) if hasattr(os, "sched_getaffinity") else os.cpu_count()
+    return max(1, int(min(_POOL_MAX, cpus or 1, _cgroup_cpus())))
+
+
+class _Pool:
+    """``width - 1`` daemon threads that run tasks beside the calling thread."""
+
+    def __init__(self, width: int) -> None:
+        self.width = width
+        self._tasks: queue.SimpleQueue = queue.SimpleQueue()
+        for n in range(width - 1):
+            threading.Thread(target=self._serve, name=f"blockstore-{n}",
+                             daemon=True).start()
+
+    def _serve(self) -> None:
+        while True:
+            fut, fn, args = self._tasks.get()
+            try:
+                fut.set_result(fn(*args))
+            except Exception as e:  # raised to the caller that waits on it
+                fut.set_exception(e)
+
+    def submit(self, fn, *args) -> Future:
+        fut = Future()
+        self._tasks.put((fut, fn, args))
+        return fut
+
+
+_pool: _Pool | None = None
+_spare_runs: list[list[bytearray]] = []  # a closed reader's run buffers, for the next
+_lock = threading.Lock()  # guards both
+
+
+def _shared_pool() -> _Pool:
+    """The process's one pool, started at its first use."""
+    global _pool
+    with _lock:
+        if _pool is None:
+            _pool = _Pool(_pool_width())
+        return _pool
+
+
+def _after_fork() -> None:  # a forked child has none of the parent's threads
+    global _pool, _lock
+    _pool, _lock = None, threading.Lock()
+
+
+if hasattr(os, "register_at_fork"):
+    os.register_at_fork(after_in_child=_after_fork)
+
+
+def _finish(jobs: list[Future]) -> BaseException | None:
+    """Wait for every job; the first one's error, if any failed."""
+    errors = [j.exception() for j in jobs]  # each call waits for its job
+    return next((e for e in errors if e is not None), None)
 
 
 @dataclass(frozen=True)
@@ -260,9 +374,10 @@ class BlockReader:
     (use :meth:`close` or the context-manager protocol), and
     :meth:`read_range` coalesces runs of contiguous uncached blocks into a
     single seek+read of at most ``MAX_RUN_BYTES`` compressed bytes, into
-    one buffer the reader reuses — the compressed blocks are back-to-back
-    on disk, so a cold sequential range costs one syscall per run instead
-    of one per block. ``stats`` accounting is unchanged: the same
+    one of two buffers the reader reuses — the compressed blocks are
+    back-to-back on disk, so a cold sequential range costs one syscall per
+    run instead of one per block. A closed reader hands its two buffers to
+    the next reader of the process, so a restore allocates none. ``stats`` accounting is unchanged: the same
     per-block useful/fetched byte and block counts as the
     one-read-per-block implementation, each block counted once.
 
@@ -274,6 +389,19 @@ class BlockReader:
     the payload, and allocates no block-sized buffers that the allocator
     may or may not hand back between restores. :meth:`read_range` is the
     same routine into a fresh buffer, returned as ``bytes``.
+
+    A run's whole blocks are decompressed in parallel: they are cut into
+    contiguous slices, one for each thread of a pool the process shares
+    (daemon threads, as many as the CPUs the process may use by affinity
+    and cgroup quota, at most ``_POOL_MAX``, the calling thread included),
+    and each thread writes its blocks into their places with its own
+    decompression context (``zstandard`` and ``zlib`` release the GIL).
+    The calling thread reads the next run, into its other buffer, before
+    it decompresses its own slice and waits for the pool. A run with fewer than two whole blocks stays on the calling
+    thread. Counts, the cache and the spans are kept on the calling
+    thread: ``blockstore.decompress`` is its wall time over a run, with
+    ``pool_blocks``, the run's blocks decompressed off it. A worker's
+    error, such as a corrupt block, is raised from the read.
     """
 
     def __init__(self, path: str, manifest: BlockManifest | None = None) -> None:
@@ -282,7 +410,8 @@ class BlockReader:
         self._data_start = _HEADER.size + 8 * (self.manifest.n_blocks + 1)
         self._cache: dict[int, bytes] = {}
         self._fetched: set[int] = set()  # blocks counted in ``stats``
-        self._run_buf = bytearray()  # compressed runs, reused
+        with _lock:  # compressed runs, read into each in turn
+            self._run_bufs = _spare_runs.pop() if _spare_runs else [bytearray(), bytearray()]
         self._codec = _make_codec(self.manifest.codec)  # decompress side: level moot
         self.stats = ReadStats()
         self._f = open(path, "rb")
@@ -293,6 +422,10 @@ class BlockReader:
         if self._f is not None:
             self._f.close()
             self._f = None
+            with _lock:  # the next reader's, so a restore allocates none
+                if not _spare_runs:
+                    _spare_runs.append(self._run_bufs)
+            self._run_bufs = None
 
     def __enter__(self) -> "BlockReader":
         return self
@@ -306,15 +439,15 @@ class BlockReader:
         except Exception:
             pass
 
-    def _read_at(self, first: int, last: int) -> memoryview:
-        """Compressed blocks [first, last], read into the reused buffer."""
+    def _read_at(self, first: int, last: int, buf: int = 0) -> memoryview:
+        """Compressed blocks [first, last], read into reused buffer ``buf``."""
         if self._f is None:
             raise ValueError(f"BlockReader for {self.path} is closed")
         m = self.manifest
         size = m.offsets[last + 1] - m.offsets[first]
-        if len(self._run_buf) < size:
-            self._run_buf = bytearray(size)
-        view = memoryview(self._run_buf)[:size]
+        if len(self._run_bufs[buf]) < size:
+            self._run_bufs[buf] = bytearray(size)
+        view = memoryview(self._run_bufs[buf])[:size]
         with span("blockstore.read", bytes=size, blocks=last + 1 - first):
             self._f.seek(self._data_start + m.offsets[first])
             self.file_reads += 1
@@ -327,34 +460,16 @@ class BlockReader:
         """Raw compressed block i — the unit streamed down FT edges."""
         return bytes(self._read_at(i, i))
 
-    def _ingest(self, i: int, comp, into: tuple[int, memoryview] | None) -> int:
-        """Decompress block ``i`` — into its place in ``into`` = (raw
-        offset, buffer) when the buffer holds the whole block, else into
-        the cache — account for the network fetch, return its raw size."""
-        n = self.manifest.block_raw_size(i)
-        lo = i * self.manifest.block_size - into[0] if into is not None else -1
-        if 0 <= lo and lo + n <= len(into[1]):
-            self._codec.decompress_into(comp, into[1][lo : lo + n])
-        else:
-            self._cache[i] = self._codec.decompress(comp, n)
-        if i not in self._fetched:
-            self._fetched.add(i)
-            self.stats.blocks_fetched += 1
-            self.stats.fetched_compressed += len(comp)
-            self.stats.fetched_raw += n
-        return n
-
     def get_block(self, i: int) -> bytes:
         if i not in self._cache:
             self._fetch_run(i, i)
         return self._cache[i]
 
-    def _fetch_run(self, first: int, last: int,
-                   into: tuple[int, memoryview] | None = None) -> None:
-        """Fetch uncached blocks [first, last] with one read per contiguous
-        run of at most ``MAX_RUN_BYTES`` (see :meth:`_ingest` for ``into``)."""
+    def _runs(self, first: int, last: int) -> list[tuple[int, int]]:
+        """The contiguous runs of uncached blocks in [first, last], each of
+        at most ``MAX_RUN_BYTES`` compressed (or one block)."""
         m = self.manifest
-        i = first
+        runs, i = [], first
         while i <= last:
             if i in self._cache:
                 i += 1
@@ -363,13 +478,66 @@ class BlockReader:
             while (j + 1 <= last and (j + 1) not in self._cache
                    and m.offsets[j + 2] - m.offsets[i] <= MAX_RUN_BYTES):
                 j += 1
-            run = self._read_at(i, j)
-            base = m.offsets[i]
-            with span("blockstore.decompress", blocks=j + 1 - i) as sp:
-                sp.set(raw_bytes=sum(
-                    self._ingest(k, run[m.offsets[k] - base : m.offsets[k + 1] - base], into)
-                    for k in range(i, j + 1)))
+            runs.append((i, j))
             i = j + 1
+        return runs
+
+    def _fetch_run(self, first: int, last: int,
+                   into: tuple[int, memoryview] | None = None) -> None:
+        """Fetch uncached blocks [first, last] with one read per run (see
+        :meth:`_runs`). A block is decompressed into its place in ``into``
+        = (raw offset, buffer) when the buffer holds it whole, on the pool
+        when the run has two such blocks or more; else into the cache."""
+        m = self.manifest
+        runs = self._runs(first, last)
+        comp = self._read_at(*runs[0]) if runs else None
+        for n, (i, j) in enumerate(runs):
+            base = m.offsets[i]
+            whole, parts = [], []
+            for k in range(i, j + 1):
+                block = comp[m.offsets[k] - base : m.offsets[k + 1] - base]
+                lo = k * m.block_size - into[0] if into is not None else -1
+                hi = lo + m.block_raw_size(k)
+                if 0 <= lo and hi <= len(into[1]):
+                    whole.append((k, block, into[1][lo:hi]))
+                else:
+                    parts.append((k, block))
+            mine, jobs = whole, []
+            if len(whole) >= 2:  # contiguous slices, the calling thread's last
+                pool = _shared_pool()
+                w = min(pool.width, len(whole))
+                cuts = [len(whole) * s // w for s in range(w + 1)]
+                jobs = [pool.submit(_decompress_blocks, m.codec, self.path, whole[a:b])
+                        for a, b in zip(cuts, cuts[1:-1])]
+                mine = whole[cuts[-2]:]
+            try:
+                # the next run is read while the pool works on this one
+                nxt = self._read_at(*runs[n + 1], (n + 1) % 2) if n + 1 < len(runs) else None
+                with span("blockstore.decompress", blocks=j + 1 - i,
+                          pool_blocks=len(whole) - len(mine)) as sp:
+                    _decompress_blocks(m.codec, self.path, mine)
+                    for k, block in parts:
+                        self._cache[k] = self._decompress(k, block)
+                    err = _finish(jobs)
+                    if err is not None:
+                        raise err
+                    sp.set(raw_bytes=sum(m.block_raw_size(k) for k in range(i, j + 1)))
+            finally:  # on an error too: no worker writes on once the read returns
+                _finish(jobs)
+            for k in range(i, j + 1):
+                if k not in self._fetched:
+                    self._fetched.add(k)
+                    self.stats.blocks_fetched += 1
+                    self.stats.fetched_compressed += m.block_compressed_size(k)
+                    self.stats.fetched_raw += m.block_raw_size(k)
+            comp = nxt
+
+    def _decompress(self, i: int, comp) -> bytes:
+        """Block ``i`` from its compressed bytes ``comp``, for the cache."""
+        try:
+            return self._codec.decompress(comp, self.manifest.block_raw_size(i))
+        except _CODEC_ERRORS as e:
+            raise ValueError(f"{self.path}: block {i}: {e}") from e
 
     # -- range-level (on-demand I/O) --------------------------------------
     def _check_range(self, offset: int, length: int) -> None:

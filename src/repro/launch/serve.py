@@ -99,6 +99,7 @@ def main(argv: list[str] | None = None) -> tuple[ColdStart, list]:
           f"amplification {s['read_amplification']:.2f}x")
     print(f"restore: read {s['read_s']*1e3:.0f} ms, decompress "
           f"{s['decompress_s']*1e3:.0f} ms ({s['blocks_decompressed']} blocks, "
+          f"{s['blocks_on_pool']} on the pool, "
           f"{s['blocks_from_cache']} from cache), decode {s['decode_s']*1e3:.0f} ms, "
           f"host-to-device {s['h2d_s']*1e3:.0f} ms ({s['h2d_bytes']/1e6:.1f} MB)")
     done = serve_requests(cs.engine, n=args.requests, prompt_len=args.prompt_len,

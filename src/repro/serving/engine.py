@@ -73,7 +73,8 @@ class ServeEngine:
         ``cold_start_stats`` then holds what the restore's spans counted:
         its host time by stage (``read_s``, ``decompress_s``, ``decode_s``
         for block joins and leaf decode, ``h2d_s``), ``h2d_bytes``,
-        ``blocks_decompressed``, ``blocks_from_cache``, and ``traces`` and
+        ``blocks_decompressed``, ``blocks_on_pool`` (those decompressed off
+        the calling thread), ``blocks_from_cache``, and ``traces`` and
         ``cache_loads``, to which the first batch's prefill adds its own.
         A lazy start adds the first pass's time (``t_first_leaves_s``),
         compressed bytes and read amplification."""
@@ -197,6 +198,7 @@ def _restore_stats(totals: dict) -> dict:
         "h2d_s": t("restore.h2d", "seconds"),
         "h2d_bytes": t("restore.h2d", "bytes"),
         "blocks_decompressed": t("blockstore.decompress", "blocks"),
+        "blocks_on_pool": t("blockstore.decompress", "pool_blocks"),
         "blocks_from_cache": t("blockstore.read_range", "blocks_cached"),
         "traces": total(totals, "traces"),
         "cache_loads": total(totals, "cache_loads"),

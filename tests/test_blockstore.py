@@ -3,6 +3,7 @@
 Property-based variants require ``hypothesis`` and are skipped when it is
 absent; deterministic example-based equivalents always run.
 """
+import math
 import os
 import random
 
@@ -435,3 +436,188 @@ def test_read_range_into_with_the_zlib_codec(tmp_path):
         out = np.empty(ln, np.uint8)
         r.read_range_into(off, ln, out)
         assert out.tobytes() == INTO_PAYLOAD[off : off + ln], (off, ln)
+
+
+# ----------------------------------------------------------------------
+# A run's whole blocks decompressed on a pool of threads
+# ----------------------------------------------------------------------
+from repro import tracing  # noqa: E402
+from repro.core import blockstore as bs  # noqa: E402
+
+POOL_PAYLOAD = random.Random(17).randbytes(40 * BS + 321)  # 41 blocks
+POOL_RANGES = {
+    "whole_payload": (0, len(POOL_PAYLOAD)),
+    "inside_blocks_at_both_ends": (BS + 99, 30 * BS + 7),
+    "on_block_boundaries": (3 * BS, 20 * BS),
+}
+CODECS = ["zlib", pytest.param("zstd", marks=pytest.mark.skipif(
+    not have_zstd(), reason="zstandard not installed"))]
+
+
+@pytest.fixture(scope="module")
+def pools():
+    """The serial path (the calling thread alone) and a pool of four."""
+    return bs._Pool(1), bs._Pool(4)
+
+
+def pool_path(tmp_path, codec):
+    path = str(tmp_path / f"{codec}.blocks")
+    write_blockstore(POOL_PAYLOAD, path, block_size=BS, codec=codec)
+    return path
+
+
+def read_on(pool, monkeypatch, path, off, ln, hole=None):
+    """``read_range_into`` on ``pool``: the reader, the bytes, the spans' totals."""
+    monkeypatch.setattr(bs, "_shared_pool", lambda: pool)
+    r = BlockReader(path)
+    if hole is not None:
+        r.get_block(hole)
+    out = np.empty(ln, np.uint8)
+    with tracing.span("test") as root:
+        r.read_range_into(off, ln, out)
+    return r, out.tobytes(), root.totals
+
+
+@pytest.mark.parametrize("runs", ["one_read", "short_runs"])
+@pytest.mark.parametrize("hole", [None, 12], ids=["no_hole", "cached_hole"])
+@pytest.mark.parametrize("case", list(POOL_RANGES))
+@pytest.mark.parametrize("codec", CODECS)
+def test_pool_gives_the_serial_bytes_and_counts(tmp_path, monkeypatch, pools,
+                                                codec, case, hole, runs):
+    if runs == "short_runs":  # several runs, so the next is read ahead
+        monkeypatch.setattr(bs, "MAX_RUN_BYTES", 5 * BS)
+    path = pool_path(tmp_path, codec)
+    off, ln = POOL_RANGES[case]
+    serial, s_bytes, s_tot = read_on(pools[0], monkeypatch, path, off, ln, hole)
+    pooled, p_bytes, p_tot = read_on(pools[1], monkeypatch, path, off, ln, hole)
+    assert s_bytes == p_bytes == POOL_PAYLOAD[off : off + ln]
+    assert pooled.stats == serial.stats
+    assert pooled.file_reads == serial.file_reads
+    assert sorted(pooled._cache) == sorted(serial._cache)
+    for key in [("blockstore.read_range", "blocks_cached"),
+                ("blockstore.decompress", "blocks"),
+                ("blockstore.decompress", "raw_bytes"),
+                ("blockstore.read", "bytes")]:
+        assert p_tot[key] == s_tot[key], key
+    assert s_tot["blockstore.decompress", "pool_blocks"] == 0
+    assert p_tot["blockstore.decompress", "pool_blocks"] > 0
+
+
+@pytest.mark.parametrize("codec", CODECS)
+def test_the_next_run_is_read_before_the_pool_is_waited_for(
+        tmp_path, monkeypatch, pools, codec):
+    monkeypatch.setattr(bs, "MAX_RUN_BYTES", 5 * BS)
+    monkeypatch.setattr(bs, "_shared_pool", lambda: pools[1])
+    names = []
+
+    def recording(name, **args):
+        names.append(name)
+        return tracing.span(name, **args)
+
+    monkeypatch.setattr(bs, "span", recording)
+    r = BlockReader(pool_path(tmp_path, codec))
+    out = np.empty(len(POOL_PAYLOAD), np.uint8)
+    r.read_range_into(0, len(POOL_PAYLOAD), out)
+    assert out.tobytes() == POOL_PAYLOAD
+    steps = [n.split(".")[1][0] for n in names[1:]]  # after read_range
+    runs = r.file_reads
+    assert runs > 2
+    assert steps == ["r"] + ["r", "d"] * (runs - 1) + ["d"]
+
+
+@pytest.mark.parametrize("where", ["worker", "caller"])
+@pytest.mark.parametrize("codec", CODECS)
+def test_a_corrupt_block_raises_value_error(tmp_path, monkeypatch, pools, codec, where):
+    """41 whole blocks over four threads: the workers take blocks 0-29,
+    the calling thread 30-40. One byte of the block's frame is flipped."""
+    path = pool_path(tmp_path, codec)
+    m = read_manifest(path)
+    k = 15 if where == "worker" else 35
+    at = bs._HEADER.size + 8 * (m.n_blocks + 1) + m.offsets[k]
+    with open(path, "r+b") as f:
+        f.seek(at)
+        byte = f.read(1)[0]
+        f.seek(at)
+        f.write(bytes([byte ^ 0xFF]))
+    monkeypatch.setattr(bs, "_shared_pool", lambda: pools[1])
+    with pytest.raises(ValueError, match=f"block {k}"):
+        BlockReader(path).read_range_into(0, m.raw_size, bytearray(m.raw_size))
+
+
+@pytest.mark.parametrize("case, on_pool", [
+    ("many_whole_blocks", True), ("one_block", False), ("two_part_blocks", False)])
+def test_pool_blocks_counts_the_blocks_off_the_calling_thread(
+        tmp_path, monkeypatch, pools, case, on_pool):
+    off, ln = {"many_whole_blocks": (0, 20 * BS), "one_block": (2 * BS, BS),
+               "two_part_blocks": (BS + 5, BS)}[case]
+    _, got, totals = read_on(pools[1], monkeypatch, pool_path(tmp_path, "zlib"), off, ln)
+    assert got == POOL_PAYLOAD[off : off + ln]
+    assert (totals["blockstore.decompress", "pool_blocks"] > 0) == on_pool
+
+
+@pytest.mark.parametrize("quota, width", [(math.inf, None), (2.5, 2), (0.5, 1)])
+def test_pool_width_follows_affinity_and_quota(monkeypatch, quota, width):
+    monkeypatch.setattr(bs, "_cgroup_cpus", lambda: quota)
+    cpus = len(os.sched_getaffinity(0))
+    want = min(bs._POOL_MAX, cpus) if width is None else min(width, cpus)
+    assert bs._pool_width() == want
+
+
+def test_concurrent_readers_share_one_pool(tmp_path, monkeypatch, pools):
+    """More calling threads than cores, each reading its own range through
+    one pool of four, with the interpreter switching threads often."""
+    import sys
+    import threading
+
+    path = pool_path(tmp_path, "zlib")
+    monkeypatch.setattr(bs, "_shared_pool", lambda: pools[1])
+    ranges = [(k * 97, len(POOL_PAYLOAD) - k * 1000) for k in range(2 * os.cpu_count() + 2)]
+    got = {}
+
+    def read(off, ln):
+        out = np.empty(ln, np.uint8)
+        BlockReader(path).read_range_into(off, ln, out)
+        got[off, ln] = out.tobytes()
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=read, args=r) for r in ranges]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    for off, ln in ranges:
+        assert got[off, ln] == POOL_PAYLOAD[off : off + ln], (off, ln)
+
+
+@pytest.mark.parametrize("files, cpus", [
+    ({}, math.inf),
+    ({"/sys/fs/cgroup/cpu.max": "max 100000"}, math.inf),
+    ({"/sys/fs/cgroup/fn/cpu.max": "250000 100000"}, 2.5),
+    ({"/sys/fs/cgroup/cpu/fn/cpu.cfs_quota_us": "150000",
+      "/sys/fs/cgroup/cpu/fn/cpu.cfs_period_us": "100000"}, 1.5),
+    ({"/sys/fs/cgroup/cpu/cpu.cfs_quota_us": "-1",
+      "/sys/fs/cgroup/cpu/cpu.cfs_period_us": "100000"}, math.inf),
+], ids=["none", "v2_max", "v2_quota", "v1_quota", "v1_none"])
+def test_cgroup_quota_is_read_in_cpus(monkeypatch, files, cpus):
+    files = {"/proc/self/cgroup": "0::/fn\n4:cpu,cpuacct:/fn\n", **files}
+    monkeypatch.setattr(bs, "_words", lambda p: files[p].split() if p in files else [])
+    assert bs._cgroup_cpus() == cpus
+
+
+def test_a_closed_readers_run_buffers_go_to_the_next_reader(into_path, monkeypatch):
+    monkeypatch.setattr(bs, "_spare_runs", [])
+    a = BlockReader(into_path)
+    assert a.read_range(0, len(INTO_PAYLOAD)) == INTO_PAYLOAD
+    bufs = a._run_bufs
+    b = BlockReader(into_path)
+    assert b._run_bufs is not bufs  # open readers share none
+    a.close()
+    b.close()  # one pair is kept: a's
+    c = BlockReader(into_path)
+    assert c._run_bufs is bufs
+    assert c.read_range(0, len(INTO_PAYLOAD)) == INTO_PAYLOAD

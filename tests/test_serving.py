@@ -83,3 +83,23 @@ def test_serve_selects_the_one_chip_cut():
     assert ONE_CHIP_CUT["reduced"] == {"n_layers": (CONFIG.n_layers, cfg.n_layers)}
     with pytest.raises(SystemExit):
         select_config("mamba2_130m", "one_chip")
+
+
+def test_lazy_restore_on_the_pool_is_bit_identical_to_the_serial_path(ckpt, monkeypatch):
+    from repro.core import blockstore as bs
+
+    mgr, params = ckpt
+    stats, trees = [], []
+    for pool in (bs._Pool(1), bs._Pool(4)):  # the calling thread alone, then four
+        monkeypatch.setattr(bs, "_shared_pool", lambda pool=pool: pool)
+        eng = ServeEngine(CFG, max_batch=2)
+        eng.start(mgr, 0, params, lazy=True)
+        stats.append(eng.cold_start_stats)
+        trees.append(eng.params)
+    for want, a, b in zip(*(jax.tree.leaves(t) for t in (params, *trees))):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes() == np.asarray(want).tobytes()
+    serial, pooled = stats
+    assert serial["blocks_on_pool"] == 0 < pooled["blocks_on_pool"]
+    assert pooled["blocks_decompressed"] == serial["blocks_decompressed"]
+    assert pooled["blocks_from_cache"] == serial["blocks_from_cache"]
+    assert pooled["h2d_bytes"] == serial["h2d_bytes"]
