@@ -325,17 +325,26 @@ PY
 # undercut the retired per-depth sweep by >= 1.1x (measured 1.13x on this
 # workload — the stagger-serialized closures are mostly single-tree, so
 # same-depth merging was already free and the wide-front gain is bounded
-# by cross-depth rounds).  The pallas cap-chain backend
-# (engine="vector_jax", run in the Pallas interpreter on the CPU) must stay
-# bit-identical to the numpy engine on a wave and a block wave.
+# by cross-depth rounds).  The counters are the burst's own engine's
+# ``VectorFlowSim.dispatch_stats``.
 python - <<'PY'
 import time
-from repro.sim import ScaleConfig, WaveConfig, provision_wave, run_scale
+from repro.sim import ScaleConfig, WaveConfig, run_scale
+from repro.sim import scale
 
 t0 = time.perf_counter()
-cfg = ScaleConfig(churn_ops=20, seed=3, wave=WaveConfig(engine="vector"))
-res = run_scale(cfg)
-ds = res.dispatch_stats
+sims = []
+make_sim = scale.make_sim
+
+
+def keep_sim(cfg):
+    sims.append(make_sim(cfg))
+    return sims[-1]
+
+
+scale.make_sim = keep_sim
+run_scale(ScaleConfig(churn_ops=20, seed=3, wave=WaveConfig(engine="vector")))
+ds = sims[0].dispatch_stats
 fronts = ds["fronts_scalar"] + ds["fronts_vector"]
 reduction = ds["legacy_levels"] / fronts
 assert reduction >= 1.1, (
@@ -348,33 +357,15 @@ assert ds["flows_vector"] > ds["flows_scalar"], (
     f"path vs {ds['flows_scalar']} scalar — the batched path is not "
     f"carrying the bulk of the work"
 )
-
-a = provision_wave("faasnet", 96, WaveConfig(engine="vector"))
-b = provision_wave("faasnet", 96, WaveConfig(engine="vector_jax"))
-assert a == b, (
-    "widefront smoke FAILED: vector_jax diverged from vector on the "
-    "96-VM wave"
-)
-from repro.core import shared_base_images
-from repro.sim import block_wave
-
-img = shared_base_images(1, 1, image_bytes=96 << 20)[0]
-bv = block_wave("faasnet", 12, WaveConfig(engine="vector"), images=img)
-bj = block_wave("faasnet", 12, WaveConfig(engine="vector_jax"), images=img)
-assert bv == bj, (
-    "widefront smoke FAILED: vector_jax diverged from vector on the "
-    "block wave"
-)
-jax_note = "vector_jax bit-identical on wave + block wave"
 elapsed = time.perf_counter() - t0
-budget = 20.0
+budget = 10.0
 assert elapsed < budget, (
     f"widefront smoke FAILED: took {elapsed:.2f} s (budget {budget} s)"
 )
 print(
     f"widefront smoke ok: {reduction:.2f}x dispatch reduction "
     f"({fronts} fronts vs {ds['legacy_levels']} per-depth sweeps), "
-    f"{jax_note}, in {elapsed*1e3:.0f} ms"
+    f"in {elapsed*1e3:.0f} ms"
 )
 PY
 

@@ -32,8 +32,8 @@ Placement policies
 ``replicated``
     Every shard holds every blob; fetchers round-robin across shards.
     Models registry *replicas* — the configuration the paper's "baseline
-    scales with registry bandwidth" claim is about, and the one
-    ``benchmarks/bench_registry_sweep.py`` sweeps.
+    scales with registry bandwidth" claim is about (pinned by
+    ``tests/test_registry.py::test_sharding_speeds_up_baseline_not_faasnet``).
 
 Naming and backward compatibility
 ---------------------------------

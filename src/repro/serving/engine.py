@@ -1,16 +1,15 @@
 """Batched serving engine with FaaSNet cold-start integration.
 
-A minimal-but-real continuous-batching server:
-  * requests enter a queue; the batcher packs up to ``max_batch`` prompts
-    (padded to a bucket length) per prefill;
-  * decode proceeds in lockstep for the active batch until each request
-    hits EOS/max_tokens;
-  * **cold start** uses the paper's on-demand path: ``start()`` lazily
+A minimal batch server:
+  * requests enter a queue; ``step_batch`` takes up to ``max_batch``
+    prompts, left-pads them to the longest, and prefills them together;
+  * decode proceeds in lockstep for that batch until every request has its
+    ``max_new_tokens``; the next batch starts only after it;
+  * **cold start** uses the paper's on-demand path: ``start()`` first
     restores only the leaves needed to begin (embedding + first stage +
-    head) via the block checkpoint, starts serving, and completes the rest
-    of the restore "in the background" (synchronously here, but the fetch
-    statistics show exactly how many bytes the fast path needed — the
-    Fig. 20 measurement on a real model).
+    head) via the block checkpoint, then loads the rest before it returns.
+    The fetch statistics show how many bytes the first pass needed — the
+    Fig. 20 measurement on a real model.
 """
 from __future__ import annotations
 
@@ -72,7 +71,7 @@ class ServeEngine:
 
         ``cold_start_stats`` then holds what the restore's spans counted:
         its host time by stage (``read_s``, ``decompress_s``, ``decode_s``
-        for block joins and leaf decode, ``h2d_s``), ``h2d_bytes``,
+        for assembling each leaf in place, ``h2d_s``), ``h2d_bytes``,
         ``blocks_decompressed``, ``blocks_on_pool`` (those decompressed off
         the calling thread), ``blocks_from_cache``, and ``traces`` and
         ``cache_loads``, to which the first batch's prefill adds its own.

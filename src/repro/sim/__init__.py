@@ -13,8 +13,6 @@ from .cluster import (
     WaveConfig,
     block_wave,
     provision_wave,
-    scalability_table,
-    startup_timeline,
 )
 from .engine import ENGINES, GBPS, FlowSim, NICConfig, SimConfig, make_sim
 from .multi_tenant import (
@@ -59,8 +57,6 @@ __all__ = [
     "WaveConfig",
     "block_wave",
     "provision_wave",
-    "scalability_table",
-    "startup_timeline",
     "GBPS",
     "ENGINES",
     "FlowSim",

@@ -66,10 +66,6 @@ class WaveConfig:
     # into SimConfig by every wave/replay entry point.
     engine: str = "incremental"
     record_trace: bool = True
-    # Vector engines: closures whose ready set is at most this many flows
-    # take the scalar per-flow path instead of the batched numpy/jax one
-    # (small-front fixed-cost crossover; see SimConfig.vector_scalar_cutoff).
-    vector_scalar_cutoff: int = 64
     # Block-level provisioning (paper §3.1–§3.2): when set, provision_wave
     # fetches this image's missing blocks per layer instead of the scalar
     # ``image_bytes * startup_fraction`` payload, and a container is ready
@@ -129,7 +125,6 @@ def provision_wave(
             coordinator_cost_s=coord_cost,
             engine=cfg.engine,
             record_trace=cfg.record_trace,
-            vector_scalar_cutoff=cfg.vector_scalar_cutoff,
         )
     )
     for vm, cap in (slow_vms or {}).items():
@@ -267,7 +262,6 @@ def block_wave(
             hop_latency=cfg.hop_latency,
             engine=cfg.engine,
             record_trace=cfg.record_trace,
-            vector_scalar_cutoff=cfg.vector_scalar_cutoff,
         )
     )
     control = cfg.rpc.control_plane_total()
@@ -332,29 +326,3 @@ def block_wave(
         }
         cache.add_image(vm, img)
     return out
-
-
-def scalability_table(
-    systems: tuple[str, ...] = SYSTEMS,
-    ns: tuple[int, ...] = (8, 16, 32, 64, 128),
-    cfg: WaveConfig | None = None,
-) -> dict[str, dict[int, dict[str, float]]]:
-    """Paper Figure 14(a): mean/min/max provisioning latency vs concurrency."""
-    out: dict[str, dict[int, dict[str, float]]] = {}
-    for system in systems:
-        out[system] = {}
-        for n in ns:
-            lat = list(provision_wave(system, n, cfg).values())
-            lat.sort()
-            out[system][n] = {
-                "mean": sum(lat) / len(lat),
-                "min": lat[0],
-                "max": lat[-1],
-                "p50": lat[len(lat) // 2],
-            }
-    return out
-
-
-def startup_timeline(system: str, n: int, cfg: WaveConfig | None = None) -> list[float]:
-    """Paper Figure 15: sorted wall-clock start times of the N functions."""
-    return sorted(provision_wave(system, n, cfg).values())

@@ -71,14 +71,10 @@ __all__ = [
 #: Engine backends selectable via :attr:`SimConfig.engine`.  They form an
 #: oracle chain — ``reference`` (full recompute, trivially correct) polices
 #: ``incremental`` (per-NIC dirty sets), which polices ``vector`` (flat
-#: numpy arrays, wide-front recompute), which in turn polices
-#: ``vector_jax`` (the same engine with the cap-chain min-kernel from
-#: ``repro.kernels.cap_chain`` on its wide fronts, run in the Pallas
-#: interpreter on the CPU; it refuses a TPU backend, whose compiler rejects
-#: the float64 kernel) — all differential-tested to produce
-#: bit-identical event logs and rates within 1e-9 (``tests/test_scale.py``,
-#: ``tests/test_vector_engine.py``).
-ENGINES = ("incremental", "vector", "vector_jax", "reference")
+#: numpy arrays, wide-front recompute).  ``vector`` matches ``incremental``
+#: bit for bit and both match ``reference`` within 1e-9
+#: (``tests/test_scale.py``, ``tests/test_vector_engine.py``).
+ENGINES = ("incremental", "vector", "reference")
 
 
 @dataclass
@@ -113,11 +109,6 @@ class SimConfig:
     # Large fleets can drop the per-event text log (the giga-burst tier
     # would otherwise materialize millions of trace tuples).
     record_trace: bool = True
-    # Vector engine only: fronts at or below this width run the scalar
-    # fast path (~40 fixed-cost numpy dispatches cost more than a handful
-    # of Python-float min chains); wider fronts take the vectorized path.
-    # Both paths are bit-identical, so this is purely a performance knob.
-    vector_scalar_cutoff: int = 64
 
     def registry_spec(self) -> RegistrySpec:
         """The effective spec (legacy knobs become a 1-shard registry)."""
@@ -196,12 +187,10 @@ def make_sim(cfg: SimConfig | None = None, *, record_rates: bool = False):
     """Build the flow simulator selected by ``cfg.engine``.
 
     The default ("incremental") is :class:`FlowSim`; "vector" selects the
-    array-based :class:`repro.sim.vector_engine.VectorFlowSim` backend,
-    "vector_jax" its :class:`~repro.sim.vector_engine.VectorJaxFlowSim`
-    subclass (cap-chain min-kernel on wide fronts, numpy fallback when jax
-    is absent) and "reference" the full-recompute oracle.  All backends
-    share ``SimConfig`` and the public API, and produce identical results
-    on the same inputs.
+    array-based :class:`repro.sim.vector_engine.VectorFlowSim` backend and
+    "reference" the full-recompute oracle.  All three share ``SimConfig``
+    and the public API, and produce identical results on the same inputs.
+    Any other name raises ``ValueError``.
     """
     cfg = cfg or SimConfig()
     if cfg.engine == "incremental":
@@ -210,10 +199,6 @@ def make_sim(cfg: SimConfig | None = None, *, record_rates: bool = False):
         from .vector_engine import VectorFlowSim
 
         return VectorFlowSim(cfg, record_rates=record_rates)
-    if cfg.engine == "vector_jax":
-        from .vector_engine import VectorJaxFlowSim
-
-        return VectorJaxFlowSim(cfg, record_rates=record_rates)
     if cfg.engine == "reference":
         from .reference import ReferenceFlowSim
 

@@ -347,7 +347,6 @@ class MultiTenantReplay:
                 hop_latency=w.hop_latency,
                 engine=w.engine,
                 record_trace=w.record_trace,
-                vector_scalar_cutoff=w.vector_scalar_cutoff,
             )
         )
         # Shard assignment is scheduler state (it rides the failover snapshot

@@ -13,8 +13,7 @@ containers across 1000 VMs in 8.3 s.  ``run_scale`` reproduces that shape:
     ONE shared :class:`~repro.sim.engine.FlowSim`, so overlapping FTs
     contend for per-VM NICs and the registry exactly as in production;
   * the result reports provisioning makespan, simulator event throughput
-    and peak registry egress — the numbers ``benchmarks/bench_scale_1000.py``
-    writes to ``BENCH_scale.json``.
+    and peak registry egress.
 
 Runs are bit-deterministic for a fixed :class:`ScaleConfig` (seeded RNG +
 the engine's (time, seq) ordering); ``tests/test_scale.py`` pins that with
@@ -128,9 +127,7 @@ def multi_tenant_config(
     … constant 256 MB), so under ``placement="shared"`` co-location on the
     4 GB VMs is genuinely memory-constrained; ``placement="exclusive"``
     reproduces the legacy one-VM-one-tenant leasing.  The returned config
-    drives :class:`repro.sim.multi_tenant.MultiTenantReplay`;
-    ``benchmarks/bench_trace_replay.py`` and
-    ``benchmarks/bench_placement.py`` are its CLI twins and the
+    drives :class:`repro.sim.multi_tenant.MultiTenantReplay`; the
     ``--runslow`` soak in ``tests/test_multi_tenant.py`` runs it with
     ``check_partition=True``.
     """
@@ -201,9 +198,8 @@ def serving_config(
     contend for per-VM CPU slots and scale-out runs in herd-controlled
     provisioning waves.  ``herd_control=False`` keeps sub-tick dispatch but
     reverts admission to the legacy one-reservation-per-deficit-unit rule —
-    the comparison baseline ``benchmarks/bench_serving.py`` measures
-    against.  Remaining ``kwargs`` pass through to
-    :func:`multi_tenant_config`.
+    the comparison baseline of herd control.  Remaining ``kwargs`` pass
+    through to :func:`multi_tenant_config`.
     """
     from .multi_tenant import ServingConfig
 
@@ -235,8 +231,7 @@ def giga_replay_config(
     images, idle reclaim and a mid-run scheduler failover, all against the
     100k-VM fleet.  Exists to prove the wide-front vector engine under the
     replay loop's interleaved ``run(until=...)`` stepping rather than one
-    monolithic ``run()``; recorded as ``giga_replay`` in
-    ``BENCH_scale.json`` by ``benchmarks/bench_scale_1000.py --giga``.
+    monolithic ``run()``.
     """
     from repro.core.image import shared_base_images
 
@@ -283,14 +278,6 @@ class ScaleResult:
     # Block mode only (cfg.images set): sim time when the last container's
     # boot working set landed — the §3.2 runnable milestone.  0.0 otherwise.
     runnable_makespan: float = 0.0
-    # Vector engines only ({} otherwise): per-run recompute dispatch
-    # telemetry — scalar-vs-vector front counts, per-front flow totals, the
-    # front-width histogram (bucket k = widths [2^(k-1), 2^k)) and the
-    # retired per-depth sweep's dispatch count (``legacy_levels``), so
-    # BENCH_scale.json can prove the wide-front batching claim from a run's
-    # own numbers: ``legacy_levels / (fronts_scalar + fronts_vector)`` is
-    # the dispatch-reduction factor.
-    dispatch_stats: dict = field(default_factory=dict)
 
 
 def _function_ids(cfg: ScaleConfig) -> list[str]:
@@ -376,7 +363,6 @@ def run_scale(cfg: ScaleConfig | None = None) -> ScaleResult:
             hop_latency=w.hop_latency,
             engine=w.engine,
             record_trace=w.record_trace,
-            vector_scalar_cutoff=w.vector_scalar_cutoff,
         )
     )
     control = w.rpc.control_plane_total()
@@ -468,19 +454,4 @@ def run_scale(cfg: ScaleConfig | None = None) -> ScaleResult:
         churn_op_s=churn_s / cfg.churn_ops if cfg.churn_ops > 0 else 0.0,
         engine=w.engine,
         runnable_makespan=max(runnable_at.values()) if runnable_at else 0.0,
-        dispatch_stats=_snapshot_dispatch_stats(sim),
     )
-
-
-def _snapshot_dispatch_stats(sim) -> dict:
-    """Deep-copied engine dispatch telemetry ({} for non-vector engines)."""
-    ds = getattr(sim, "dispatch_stats", None)
-    if not ds:
-        return {}
-    out = dict(ds)
-    out["front_width_hist"] = dict(ds.get("front_width_hist", {}))
-    fronts = out.get("fronts_scalar", 0) + out.get("fronts_vector", 0)
-    out["dispatch_reduction"] = (
-        out.get("legacy_levels", 0) / fronts if fronts else 0.0
-    )
-    return out

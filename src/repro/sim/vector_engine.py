@@ -23,6 +23,9 @@ chained through dict registries.  The incremental engine's per-flow
   running sums, the rate log) is deferred to a single ``(depth, fid)``-
   sorted pass at the end of the call, which reproduces the incremental
   engine's add sequence bit-for-bit;
+* a front of at most ``SCALAR_CUTOFF`` flows runs the same math per flow
+  in plain Python floats instead, where numpy's fixed cost per dispatch
+  would dominate; the two paths give the same bits;
 * completion times are batch-computed as ``t_last + remaining / rate`` over
   the changed slice and fed to the same lazily-invalidated epoch heap, with
   all same-timestamp completions extracted in one batch.
@@ -53,11 +56,17 @@ from repro.core.topology import DistributionPlan, Flow
 
 from .engine import SimConfig, plan_releases, wire_runnable
 
-__all__ = ["VectorFlowSim", "VectorJaxFlowSim"]
+__all__ = ["VectorFlowSim"]
 
 _F64 = np.float64
 _I64 = np.int64
 _EMPTY_I64 = np.empty(0, dtype=_I64)  # shared read-only seed for node fid bases
+
+#: Fronts of at most this many flows take the scalar per-flow path: ~40
+#: fixed-cost numpy dispatches cost more than a handful of Python-float min
+#: chains.  Wider fronts take the vectorized path.  Both paths give the same
+#: bits, so the front's width alone decides.
+SCALAR_CUTOFF = 64
 
 
 class _VFlowState:
@@ -226,8 +235,8 @@ class VectorFlowSim:
         # counts the per-depth sweeps the retired depth-level algorithm would
         # have dispatched on the same closures (one per distinct streaming
         # depth per call), so ``legacy_levels / (fronts_scalar +
-        # fronts_vector)`` is the front-widening factor BENCH_scale.json
-        # records.  ``front_width_hist`` keys are ``width.bit_length()``
+        # fronts_vector)`` is the front-widening factor.
+        # ``front_width_hist`` keys are ``width.bit_length()``
         # (i.e. bucket k holds fronts of width [2^(k-1), 2^k)).
         self.dispatch_stats: dict = {
             "recompute_calls": 0,
@@ -549,7 +558,7 @@ class VectorFlowSim:
         if not buf:
             return
         cfg = self.cfg
-        cutoff = cfg.vector_scalar_cutoff
+        cutoff = SCALAR_CUTOFF
         stats = self.dispatch_stats
         if len(buf) <= 48:
             # Small closure: dedup/sort/filter in plain Python, and when the
@@ -795,7 +804,7 @@ class VectorFlowSim:
         executed per flow in plain Python: every float64 operation runs on
         the same values in the same order, so rates, heap keys, running
         registry/NIC sums and peak telemetry are all bit-identical.  Fronts
-        are routed by the same ``vector_scalar_cutoff`` rule — a wide
+        are routed by the same ``SCALAR_CUTOFF`` rule — a wide
         cascade generation still goes through :meth:`_vector_front` — so
         the dispatch telemetry (front counts, width histogram, legacy-level
         equivalents) matches what the array path would record.
@@ -841,7 +850,7 @@ class VectorFlowSim:
                         break
                     p = fpar_a[p]
                 sget(r, []).append(fid)
-        cutoff = cfg.vector_scalar_cutoff
+        cutoff = SCALAR_CUTOFF
         sc_fids: list[int] = []
         sc_old: list[float] = []
         sc_new: list[float] = []
@@ -981,11 +990,10 @@ class VectorFlowSim:
     def _front_rates(
         self, fids: np.ndarray, src: np.ndarray, dst: np.ndarray
     ) -> np.ndarray:
-        """Elementwise min-cap chain over one ready front (numpy path).
+        """Elementwise min-cap chain over one ready front.
 
-        Seam for the accelerator tier: :class:`VectorJaxFlowSim` overrides
-        this with the fused jax/pallas kernel; everything around it (fronts,
-        settles, heaps, deferred accounting) is shared.
+        The array twin of the scalar path's per-flow ``min`` chain, with the
+        same operands in the same order.
         """
         cfg = self.cfg
         n_out = self._nout_cnt[src]
@@ -1597,73 +1605,3 @@ class VectorFlowSim:
             if f.done:
                 out[f.flow.dst] = max(out.get(f.flow.dst, 0.0), f.t_done)
         return out
-
-
-class VectorJaxFlowSim(VectorFlowSim):
-    """Vector engine with the fused jax/pallas cap-chain kernel on wide fronts.
-
-    Fronts wider than ``cfg.vector_scalar_cutoff`` route the per-flow
-    min-cap chain through :func:`repro.kernels.cap_chain.cap_chain_rates`
-    — a fused elementwise-minima kernel run in float64 (so its IEEE-754
-    results are bit-identical to the numpy path; see the kernel module for
-    the dtype argument).  Narrow fronts keep the scalar fast path.  The
-    event log is bit-identical to :class:`VectorFlowSim`, which stays the
-    policing oracle for this tier exactly as the incremental engine polices
-    the vector one.
-
-    The kernel runs in the Pallas interpreter, which is what the CPU
-    backend offers.  A TPU backend is refused: the v5e compiler rejects the
-    float64 kernel, and interpreting it there would hide the device.
-    """
-
-    def __init__(self, cfg: SimConfig | None = None, *, record_rates: bool = False):
-        import jax
-
-        backend = jax.default_backend()
-        if backend != "cpu":
-            raise RuntimeError(
-                f"engine='vector_jax' runs its float64 pallas kernel in the "
-                f"Pallas interpreter on the CPU only; the backend is "
-                f"{backend!r}, and the TPU v5e compiler refuses 64-bit pallas "
-                f"kernels"
-            )
-        super().__init__(cfg, record_rates=record_rates)
-        self.dispatch_stats["fronts_jax"] = 0
-        self.dispatch_stats["flows_jax"] = 0
-
-    def _front_rates(
-        self, fids: np.ndarray, src: np.ndarray, dst: np.ndarray
-    ) -> np.ndarray:
-        from repro.kernels.cap_chain import cap_chain_rates
-
-        cfg = self.cfg
-        # Tiny numpy gathers feed the kernel; the fused min chain itself —
-        # the ~10 elementwise dispatches the numpy path pays — runs in
-        # pallas.  The parent cap is gathered as +inf where absent or
-        # already done, matching the numpy path's masked minimum.
-        par = self._fpar[fids]
-        pr = np.full(fids.size, np.inf, dtype=_F64)
-        pm = par >= 0
-        if pm.any():
-            pi = np.flatnonzero(pm)
-            live = ~self._fdone[par[pi]]
-            if not live.all():
-                pi = pi[live]
-            if pi.size:
-                pr[pi] = self._rate[par[pi]]
-        stats = self.dispatch_stats
-        stats["fronts_jax"] += 1
-        stats["flows_jax"] += int(fids.size)
-        return cap_chain_rates(
-            self._nout_cnt[src],
-            self._nin_cnt[dst],
-            self._nout_cap[src],
-            self._nqps[src],
-            pr,
-            self._fblk[fids],
-            per_stream_cap=cfg.per_stream_cap,
-            in_cap=cfg.vm_nic.in_cap,
-            decompress_rate=cfg.decompress_rate,
-            block_size=cfg.block_size,
-            interpret=True,  # the CPU backend, checked in __init__
-        )

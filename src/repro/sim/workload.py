@@ -38,7 +38,7 @@ class ReplayConfig:
     vm_pool_size: int = 1000
     idle_reclaim_s: float = 7 * 60.0  # scaled-down 15-min production policy
     # The trace tests run against the region-scale production registry, not
-    # the 128-VM devcluster one the microbenchmarks were calibrated against
+    # the 128-VM devcluster one ``provision_wave`` (paper §4.3) is calibrated to
     # (paper §4.1 vs §4.2 use different deployments).
     registry_out_cap: float = 52 * GBPS  # bytes/s (52 Gbps region registry)
     registry_qps: float = 700.0

@@ -10,7 +10,7 @@ Two safety nets for the incremental-rate engine refactor:
     (:class:`repro.sim.reference.ReferenceFlowSim`) to ±1e-9.
 
 The full 2500-containers / 1000-VM burst is marked ``slow`` (run with
-``--runslow``); ``benchmarks/bench_scale_1000.py`` is its CLI twin.
+``--runslow``).
 """
 import random
 import time

@@ -1,9 +1,8 @@
 """VectorFlowSim: differential verification against the other engines.
 
-The vector engine is the third member of the oracle chain (``reference`` →
-``incremental`` → ``vector`` → ``vector_jax``, see
-``repro.sim.engine.ENGINES``) and is held to a *stricter* bar than the
-incremental engine was:
+The vector engine is the last member of the oracle chain (``reference`` →
+``incremental`` → ``vector``, see ``repro.sim.engine.ENGINES``) and is held
+to a *stricter* bar than the incremental engine was:
 
   * against the incremental engine it must be **bit-identical** — event
     logs compare equal as exact floats (run_scale trace, provision-wave
@@ -11,11 +10,9 @@ incremental engine was:
     exactly;
   * against the reference oracle it must agree to ±1e-9 on completion
     times and peak egress, like the incremental engine does;
-  * the ``vector_jax`` tier (fused pallas cap-chain kernel, run in the
-    Pallas interpreter) must be bit-identical to ``vector`` — and both
-    must be cutoff-invariant: forcing every ready front down the wide
-    vectorized/pallas path (``vector_scalar_cutoff=0``) may not change a
-    single bit.
+  * it must be cutoff-invariant: forcing every ready front down the wide
+    vectorized path (``vector_engine.SCALAR_CUTOFF`` patched to 0) may not
+    change a single bit.
 
 Randomized plans + churn (seeded always; hypothesis variant when the
 package is installed) drive the engines through the same scenarios,
@@ -24,8 +21,8 @@ including mid-flight ``set_parent`` and slow-VM re-rating.  The
 engines; the wide-front dispatch telemetry (``dispatch_stats``) is fuzzed
 for internal consistency against event counts.
 """
-import dataclasses
 import random
+from unittest import mock
 
 import pytest
 
@@ -41,8 +38,9 @@ from repro.core.topology import (
 )
 from repro.sim import ScaleConfig, WaveConfig, provision_wave, run_scale
 from repro.sim.engine import ENGINES, FlowSim, SimConfig, make_sim
+from repro.sim import vector_engine
 from repro.sim.reference import ReferenceFlowSim
-from repro.sim.vector_engine import VectorFlowSim, VectorJaxFlowSim
+from repro.sim.vector_engine import VectorFlowSim
 
 try:
     from hypothesis import HealthCheck, given, settings
@@ -76,7 +74,7 @@ def _run_engine(cls, plan, cfg, *, slow_vms=None):
 
 
 def _assert_bit_identical(inc, inc_states, other, other_states):
-    """``other`` (a vector-family engine) matches ``inc`` exactly."""
+    """``other`` (a vector engine run) matches ``inc`` exactly."""
     assert other.now == inc.now
     assert other.trace == inc.trace
     assert other.events_processed == inc.events_processed
@@ -90,50 +88,35 @@ def _assert_bit_identical(inc, inc_states, other, other_states):
         assert a.remaining == b.remaining and a.rate == b.rate
 
 
-def _base_stats(sim):
-    """dispatch_stats minus the jax-only counters (subset of vector's)."""
-    return {
-        k: v
-        for k, v in sim.dispatch_stats.items()
-        if k not in ("fronts_jax", "flows_jax")
-    }
+def _assert_three_way(plan, cfg: SimConfig, *, slow_vms=None):
+    """One plan through all three engines: pairwise agreement.
 
-
-def _assert_four_way(plan, cfg: SimConfig, *, slow_vms=None):
-    """One plan through all four engines: pairwise agreement.
-
-    vector and vector_jax vs incremental are exact (same floats); vector
-    vs reference is ±1e-9 — the reference engine re-rates after every
-    single event, so a batch of same-instant completions can take a
-    microscopically different arithmetic path.  Both vector tiers are also
-    re-run with ``vector_scalar_cutoff=0`` so every ready front takes the
-    wide vectorized (resp. pallas) path — the cutoff
-    is a pure performance knob and may not change a single bit.
+    vector vs incremental is exact (same floats); vector vs reference is
+    ±1e-9 — the reference engine re-rates after every single event, so a
+    batch of same-instant completions can take a microscopically different
+    arithmetic path.  The vector engine is also re-run with
+    ``SCALAR_CUTOFF`` patched to 0 so every ready front takes the wide
+    vectorized path — the front's width only picks between two paths that
+    may not differ by a single bit.
     """
     inc, inc_states = _run_engine(FlowSim, plan, cfg, slow_vms=slow_vms)
     vec, vec_states = _run_engine(VectorFlowSim, plan, cfg, slow_vms=slow_vms)
     ref, ref_states = _run_engine(ReferenceFlowSim, plan, cfg, slow_vms=slow_vms)
-    jx, jx_states = _run_engine(VectorJaxFlowSim, plan, cfg, slow_vms=slow_vms)
 
-    # vector / vector_jax vs incremental: bit-identical
+    # vector vs incremental: bit-identical
     _assert_bit_identical(inc, inc_states, vec, vec_states)
-    _assert_bit_identical(inc, inc_states, jx, jx_states)
-    assert _base_stats(jx) == _base_stats(vec)
 
     # cutoff invariance: every front forced down the wide path
-    wide = dataclasses.replace(cfg, vector_scalar_cutoff=0)
-    vec0, vec0_states = _run_engine(VectorFlowSim, plan, wide, slow_vms=slow_vms)
-    jx0, jx0_states = _run_engine(VectorJaxFlowSim, plan, wide, slow_vms=slow_vms)
+    with mock.patch.object(vector_engine, "SCALAR_CUTOFF", 0):
+        vec0, vec0_states = _run_engine(
+            VectorFlowSim, plan, cfg, slow_vms=slow_vms
+        )
     _assert_bit_identical(inc, inc_states, vec0, vec0_states)
-    _assert_bit_identical(inc, inc_states, jx0, jx0_states)
     s, s0 = vec.dispatch_stats, vec0.dispatch_stats
     # the front decomposition is cutoff-independent; only the path differs
     assert s0["fronts_scalar"] == 0 and s0["flows_scalar"] == 0
     assert s0["fronts_vector"] == s["fronts_scalar"] + s["fronts_vector"]
     assert s0["front_width_hist"] == s["front_width_hist"]
-    # every wide front went through the pallas kernel
-    assert jx0.dispatch_stats["fronts_jax"] == s0["fronts_vector"]
-    assert jx0.dispatch_stats["flows_jax"] == s0["flows_vector"]
 
     # vector vs reference: 1e-9 completion times + peak egress
     assert _close(vec.now, ref.now)
@@ -150,14 +133,14 @@ def _assert_four_way(plan, cfg: SimConfig, *, slow_vms=None):
 
 
 # ----------------------------------------------------------------------
-# Canonical topologies through all four engines
+# Canonical topologies through all three engines
 # ----------------------------------------------------------------------
 def test_four_way_faasnet_tree():
     ft = FunctionTree("f")
     for i in range(15):
         ft.insert(f"vm{i}")
     plan = faasnet_plan(ft, image_bytes=int(100 * MB), startup_fraction=0.2)
-    _assert_four_way(plan, _wave_simconfig())
+    _assert_three_way(plan, _wave_simconfig())
 
 
 def test_four_way_faasnet_tree_with_straggler():
@@ -165,7 +148,7 @@ def test_four_way_faasnet_tree_with_straggler():
     for i in range(15):
         ft.insert(f"vm{i}")
     plan = faasnet_plan(ft, image_bytes=int(100 * MB), startup_fraction=0.2)
-    _assert_four_way(plan, _wave_simconfig(), slow_vms={"vm1": 2 * MB})
+    _assert_three_way(plan, _wave_simconfig(), slow_vms={"vm1": 2 * MB})
 
 
 def test_four_way_registry_star():
@@ -174,7 +157,7 @@ def test_four_way_registry_star():
         image_bytes=int(100 * MB),
         startup_fraction=0.2,
     )
-    _assert_four_way(plan, _wave_simconfig())
+    _assert_three_way(plan, _wave_simconfig())
 
 
 def test_four_way_kraken_mesh():
@@ -184,7 +167,7 @@ def test_four_way_kraken_mesh():
         origin="origin",
         seed=7,
     )
-    _assert_four_way(plan, _wave_simconfig(coordinator_cost_s=0.070))
+    _assert_three_way(plan, _wave_simconfig(coordinator_cost_s=0.070))
 
 
 def test_four_way_sharded_registry():
@@ -197,7 +180,7 @@ def test_four_way_sharded_registry():
         startup_fraction=0.25,
         registry=spec,
     )
-    _assert_four_way(plan, _wave_simconfig(registry=spec))
+    _assert_three_way(plan, _wave_simconfig(registry=spec))
 
 
 # ----------------------------------------------------------------------
@@ -261,25 +244,14 @@ def test_make_sim_selects_backend():
     assert isinstance(make_sim(SimConfig()), FlowSim)
     assert isinstance(make_sim(SimConfig(engine="vector")), VectorFlowSim)
     assert isinstance(make_sim(SimConfig(engine="reference")), ReferenceFlowSim)
-    jx = make_sim(SimConfig(engine="vector_jax"))
-    assert isinstance(jx, VectorJaxFlowSim)
-    assert isinstance(jx, VectorFlowSim)  # subclass: shares the whole engine
-    assert jx.dispatch_stats["fronts_jax"] == 0  # kernel fronts, counted from the start
-    assert set(ENGINES) == {"incremental", "vector", "vector_jax", "reference"}
+    assert ENGINES == ("incremental", "vector", "reference")
 
 
-def test_vector_jax_refuses_tpu_backend(monkeypatch):
-    """The float64 kernel does not compile for a TPU; no silent interpreter."""
-    import jax
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with pytest.raises(RuntimeError, match="refuses 64-bit pallas"):
-        make_sim(SimConfig(engine="vector_jax"))
-
-
-def test_make_sim_rejects_unknown_engine():
+@pytest.mark.parametrize("engine", ["nope", "vector_jax"])
+def test_make_sim_rejects_unknown_engine(engine):
+    """An unknown name, or a retired engine's in an old config, fails loudly."""
     with pytest.raises(ValueError, match="unknown engine"):
-        make_sim(SimConfig(engine="gpu"))
+        make_sim(SimConfig(engine=engine))
 
 
 def test_giga_burst_config_shape():
@@ -467,13 +439,9 @@ def test_random_plan_churn_four_way_fuzz():
         inc = _churned_run(FlowSim, plan, cfg, churn)
         vec = _churned_run(VectorFlowSim, plan, cfg, churn)
         ref = _churned_run(ReferenceFlowSim, plan, cfg, churn)
-        jx = _churned_run(VectorJaxFlowSim, plan, cfg, churn)
         assert vec.trace == inc.trace, seed
         assert vec.completion_times() == inc.completion_times(), seed
         assert vec.peak_shard_egress == inc.peak_shard_egress, seed
-        assert jx.trace == vec.trace, seed
-        assert jx.completion_times() == vec.completion_times(), seed
-        assert _base_stats(jx) == _base_stats(vec), seed
         ct_v, ct_r = vec.completion_times(), ref.completion_times()
         assert set(ct_v) == set(ct_r), seed
         for k, v in ct_v.items():
@@ -505,8 +473,8 @@ def test_dispatch_telemetry_fuzz_consistency():
             cap = None if rng.random() < 0.3 else rng.uniform(1, 40) * MB
             churn.append((0.2 + 0.3 * k, vm, cap))
         cutoff = rng.choice([0, 2, 64])
-        cfg = _wave_simconfig(vector_scalar_cutoff=cutoff)
-        vec = _churned_run(VectorFlowSim, plan, cfg, churn)
+        with mock.patch.object(vector_engine, "SCALAR_CUTOFF", cutoff):
+            vec = _churned_run(VectorFlowSim, plan, _wave_simconfig(), churn)
         s = vec.dispatch_stats
         fronts = s["fronts_scalar"] + s["fronts_vector"]
         flows = s["flows_scalar"] + s["flows_vector"]
@@ -528,8 +496,8 @@ def test_dispatch_telemetry_fuzz_consistency():
         ), (seed, s["recompute_calls"], vec.events_processed)
 
 
-def test_blocks_on_warm_cache_four_way():
-    """Blocks-on provisioning with a warm block cache: four-way agreement.
+def test_blocks_on_warm_cache_four_way(monkeypatch):
+    """Blocks-on provisioning with a warm block cache: the engines agree.
 
     Block-granular flows exercise the QPS-throttle leg of the cap chain
     (`block_size * qps / n_out`), and a warm cache makes the plans sparse
@@ -539,15 +507,12 @@ def test_blocks_on_warm_cache_four_way():
 
     imgs = shared_base_images(6, 2, image_bytes=int(48 * MB))
     results = {}
-    for name, cls in (
-        ("inc", FlowSim),
-        ("vec", VectorFlowSim),
-        ("jax", VectorJaxFlowSim),
-    ):
+    for name, cls in (("inc", FlowSim), ("vec", VectorFlowSim)):
         for cutoff in (0, 64):
             if name == "inc" and cutoff == 0:
-                continue  # the knob only exists on the vector tiers
-            sim = cls(SimConfig(record_trace=True, vector_scalar_cutoff=cutoff))
+                continue  # the cutoff only exists in the vector engine
+            monkeypatch.setattr(vector_engine, "SCALAR_CUTOFF", cutoff)
+            sim = cls(SimConfig(record_trace=True))
             cache = BlockCache()
             cache.add_image("seed", imgs[0])  # warm: base layers resident
             runnable, done = {}, {}
